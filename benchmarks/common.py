@@ -4,6 +4,8 @@ from __future__ import annotations
 import time
 from typing import Callable, List, Tuple
 
+import jax
+
 
 Row = Tuple[str, float, str]   # (name, us_per_call, derived)
 
@@ -14,13 +16,7 @@ def time_us(fn: Callable, *args, iters: int = 20, warmup: int = 3) -> float:
     t0 = time.perf_counter()
     for _ in range(iters):
         out = fn(*args)
-    # block on jax arrays
-    try:
-        import jax
-
-        jax.block_until_ready(out)
-    except Exception:
-        pass
+    jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters * 1e6
 
 

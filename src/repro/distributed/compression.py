@@ -125,9 +125,7 @@ def compressed_psum(tree, mesh, axis: str = "pod"):
 
     leaves, treedef = jax.tree.flatten(tree)
     specs = tuple(P(*(None,) * leaf.ndim) for leaf in leaves)
-    from repro.jax_compat import shard_map
-
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh, in_specs=specs, out_specs=specs, check_vma=False
     )(*leaves)
     return treedef.unflatten(list(out))

@@ -131,9 +131,12 @@ class TierSpec:
                                       # between 0 and this every tick
     spec_accept_floor: float = 0.3    # tier acceptance EWMA below which
                                       # the controller drives k -> 0
+    reduced: bool = True              # False => the published config (full
+                                      # depth and width, bf16); ROADMAP R1's
+                                      # chip-share cut replaces this flag
     model_overrides: Optional[Dict[str, object]] = None
                                       # ModelConfig field overrides applied
-                                      # on top of get_config(arch).reduce()
+                                      # on top of the (reduced) config
                                       # (dataclasses.replace) — the decode-
                                       # bound benches size the model so the
                                       # wide verify step has real compute
@@ -570,12 +573,14 @@ class FleetRuntime:
             from repro.models import Model
 
             overrides = dict(spec.model_overrides or {})
-            mkey = (spec.arch, spec.param_seed,
+            mkey = (spec.arch, spec.param_seed, spec.reduced,
                     tuple(sorted(overrides.items())))
             if mkey not in self._model_cache:
                 import dataclasses
 
-                cfg = get_config(spec.arch).reduce()
+                cfg = get_config(spec.arch)
+                if spec.reduced:
+                    cfg = cfg.reduce()
                 if overrides:
                     cfg = dataclasses.replace(cfg, **overrides)
                 model = Model(cfg)
@@ -1434,8 +1439,10 @@ def build_demo_fleet(
     hedge_fraction: float = 0.0,
     paged: bool = False,
     seed: int = 0,
+    reduced: bool = True,
 ) -> FleetRuntime:
-    """A heterogeneous 2-tier fleet over reduced-config engines.
+    """A heterogeneous 2-tier fleet over reduced-config engines
+    (``reduced=False``: the arch's published config).
 
     ``cheap`` has low $/hr but small decode batches (low per-replica
     throughput); ``premium`` costs more per hour but decodes twice the
@@ -1457,12 +1464,12 @@ def build_demo_fleet(
                  nominal_t_max=1.0, latency_s=2.0, decode_batch=2,
                  decode_chunk=4, queue_limit=6, base_capacity=6,
                  provision_delay_s=3.0, initial_replicas=2,
-                 paged_kv=paged, page_size=8),
+                 paged_kv=paged, page_size=8, reduced=reduced),
         TierSpec(name="premium", arch=arch, cost_per_hour=4.0,
                  nominal_t_max=2.0, latency_s=1.0, decode_batch=4,
                  decode_chunk=4, queue_limit=8, base_capacity=4,
                  provision_delay_s=3.0, initial_replicas=1,
-                 paged_kv=paged, page_size=8),
+                 paged_kv=paged, page_size=8, reduced=reduced),
     ]
     pool_events = None
     if outage is not None:

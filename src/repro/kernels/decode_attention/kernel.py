@@ -1,47 +1,40 @@
-"""Flash-decoding Pallas TPU kernels: one query token vs a long KV cache.
+"""Flash-decoding Pallas TPU kernels: a few query tokens vs a long KV cache.
 
 Decode attention is HBM-bandwidth-bound (the whole cache is read once per
 token), so the kernel's job is to stream KV through VMEM in large tiles
-while keeping the online-softmax state for the GQA head-group in registers/
-VMEM scratch.  Grid: (B, Hkv, S/bk) — KV tiles innermost; the q tile is the
-(G, D) head-group so the MXU sees a (G, D)×(D, bk) matmul per tile.
+while keeping the online-softmax state in VMEM scratch.  Tiles past a
+sequence's length are skipped entirely with @pl.when — for a 32k-token
+budget cache holding 2k tokens that is a 16× read saving over the masked
+dense einsum (the lax baseline).
 
-Tiles past ``lengths[b]`` are skipped entirely with @pl.when — for a
-32k-token budget cache holding 2k tokens that is a 16× read saving over the
-masked dense einsum (the lax baseline).
+One kernel body serves every entry point.  Its q operand is a q-chunk
+(B, Q, Hq, D) regrouped to (B, Hkv, Q·G, D): row r of KV head h is query
+``r // G`` of the chunk, group member ``r % G``, at absolute position
+``cache_lens[b] + r // G``, allowed keys ``<= cache_lens[b] + r // G``.
+Decode is the Q = 1 case with ``cache_lens = lengths - 1``; the mixed-batch
+engine step (``mixed_attention_*``) fuses decode rows and prefill chunks
+into the same call.
 
-Two variants:
+Grid: (B, K, tiles per split).  Each step DMAs one (bk, Hkv, D) KV tile —
+all KV heads of bk positions, the cache's own (B, S, Hkv, D) layout, so the
+block's last two dims cover the whole (Hkv, D) extent as Mosaic requires —
+and walks the Hkv heads in-kernel with a (Q·G, D)×(D, bk) MXU matmul each.
+The per-sequence lengths (and the paged block tables) ride in as
+*scalar-prefetch* operands (``pltpu.PrefetchScalarGridSpec``) so they live
+in SMEM, and the paged index_map resolves ``tables[b, j]`` before the tile
+DMA issues — the KV gather happens inside the grid, not as a materialized
+(B, S, Hkv, D) copy in HBM.
 
-* ``decode_attention_pallas`` — single-stage: each (b, hkv) cell walks its
-  KV tiles *sequentially*, so grid parallelism is only B·Hkv wide.
-* ``decode_attention_splitk`` — two-stage flash-decoding split-K: the cache
-  is cut into ``k_splits`` chunks, each chunk's grid cell produces a
-  *partial* online-softmax state (m, l, acc), and a combine kernel merges
-  the K partials with the standard log-sum-exp rescaling.  Long caches at
-  small B·Hkv then parallelize across B·Hkv·K grid cells — the exact
-  flash-decoding decomposition (Dao et al.), and the layout the scheduler's
-  t_max measurement rewards for decode_32k/long_500k cells.
+* single-stage (K = 1): each sequence walks its KV tiles sequentially and
+  normalizes in the last step.
+* split-K (K > 1, flash-decoding, Dao et al.): the cache is cut into K
+  chunks, each chunk's grid cells emit an *unnormalized* partial state
+  (m, l, acc), and ``_combine`` merges the K partials with the standard
+  log-sum-exp rescaling — long caches at small B then spread over B·K
+  grid cells.
 
-Mixed-batch chunked prefill (``mixed_attention_pallas`` / ``mixed_
-attention_paged``): the q operand generalizes from one token to a q-chunk
-(B, Q, Hq, D) — each sequence processes Q new tokens whose absolute
-positions are ``cache_lens[b] + i``.  The chunk rides the SAME grid as
-flash decoding: q is regrouped to (B, Hkv, Q·G, D) so the MXU sees a
-(Q·G, D)×(D, bk) matmul per KV tile, and the only change to the online
-softmax is a *per-row* causal limit (row r = query ``r // G`` may see keys
-``<= cache_lens[b] + r // G``) instead of one scalar length.  Q = 1
-degenerates to the decode kernel exactly, which is why one kernel family
-serves decode steps, prefill chunks, and the fused mixture of both.
-
-Paged variants (``decode_attention_paged`` / ``decode_attention_paged_
-splitk``): KV lives in a shared page pool (P, page_size, Hkv, D) and each
-sequence names its pages in a (B, n_blocks) block table.  The tables (and
-per-sequence lengths) ride in as *scalar-prefetch* operands
-(``pltpu.PrefetchScalarGridSpec``) so the BlockSpec index_map can resolve
-``tables[b, j]`` before the tile DMA issues — the KV gather happens inside
-the grid, not as a materialized (B, S, Hkv, D) copy in HBM.  One grid step
-streams one physical page; the online-softmax state and the split-K
-combine are shared with the contiguous kernels.
+Paged variants read KV from a shared page pool (P, page_size, Hkv, D)
+through a (B, n_blocks) block table; one grid step streams one page.
 """
 from __future__ import annotations
 
@@ -51,494 +44,27 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
-def _decode_kernel(
-    len_ref,                      # (1,) int32 valid length for this b
-    q_ref, k_ref, v_ref, o_ref,   # (1,G,D), (1,bk,1,D), (1,bk,1,D), (1,G,D)
-    m_ref, l_ref, acc_ref,        # scratch (G,), (G,), (G,D)
-    *,
-    bk: int, nk: int, scale: float,
-):
-    kj = pl.program_id(2)
-    length = len_ref[0]
+def _attend_kernel(*refs, n_prefetch: int, bk: int, nkc: int, Q: int, G: int,
+                   scale: float, split: bool):
+    """Online softmax over one (bk, Hkv, D) KV tile for every KV head.
 
-    @pl.when(kj == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(kj * bk < length)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                 # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bk, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                           # (G, bk)
-        pos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-
-    @pl.when(kj == nk - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(
-            o_ref.dtype
-        )
-
-
-def decode_attention_pallas(
-    q: jax.Array,          # (B, Hq, D)
-    k_cache: jax.Array,    # (B, S, Hkv, D)
-    v_cache: jax.Array,
-    lengths: jax.Array,    # (B,) int32
-    *,
-    block_k: int = 512,
-    softmax_scale=None,
-    interpret: bool = False,
-) -> jax.Array:
-    B, S, Hkv, D = k_cache.shape
-    Hq = q.shape[1]
-    G = Hq // Hkv
-    scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    bk = min(block_k, S)
-    assert S % bk == 0
-    nk = S // bk
-
-    qg = q.reshape(B, Hkv, G, D)
-    grid = (B, Hkv, nk)
-    kernel = functools.partial(_decode_kernel, bk=bk, nk=nk, scale=scale)
-    from repro.kernels.flash_attention.kernel import pltpu_vmem
-
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, kj: (b,)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, kj: (b, h, 0, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, kj: (b, kj, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, kj: (b, kj, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, kj: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu_vmem((G,), jnp.float32),
-            pltpu_vmem((G,), jnp.float32),
-            pltpu_vmem((G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
-    return out.reshape(B, Hq, D)
-
-
-# ---------------------------------------------------------------------------
-# split-K flash decoding (two-stage)
-# ---------------------------------------------------------------------------
-
-
-def _splitk_partial_kernel(
-    len_ref,                      # (1,) int32 valid length for this b
-    q_ref, k_ref, v_ref,          # (1,1,G,D), (1,bk,1,D), (1,bk,1,D)
-    m_out, l_out, acc_out,        # (1,1,1,G), (1,1,1,G), (1,1,1,G,D)
-    m_ref, l_ref, acc_ref,        # scratch (G,), (G,), (G,D)
-    *,
-    bk: int, nkc: int, scale: float,
-):
-    """Stage 1: per-chunk online softmax.  Grid (B, Hkv, K, ck/bk); the
-    innermost dim walks this chunk's KV tiles, scratch carries the state,
-    and the last tile writes the chunk's *unnormalized* partials."""
-    kc = pl.program_id(2)
-    kj = pl.program_id(3)
-    length = len_ref[0]
-
-    @pl.when(kj == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    tile_start = (kc * nkc + kj) * bk
-
-    @pl.when(tile_start < length)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                 # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bk, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                           # (G, bk)
-        pos = tile_start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-
-    @pl.when(kj == nkc - 1)
-    def _finalize():
-        # chunks entirely past `length` emit the identity state
-        # (m=-inf, l=0, acc=0) — the combine kernel's rescale zeroes them.
-        m_out[0, 0, 0] = m_ref[...]
-        l_out[0, 0, 0] = l_ref[...]
-        acc_out[0, 0, 0] = acc_ref[...]
-
-
-def _splitk_combine_kernel(m_ref, l_ref, acc_ref, o_ref):
-    """Stage 2: merge K partial softmax states.  Grid (B, Hkv)."""
-    m = m_ref[0, 0]                                         # (K, G)
-    l = l_ref[0, 0]                                         # (K, G)
-    acc = acc_ref[0, 0]                                     # (K, G, D)
-    m_star = jnp.max(m, axis=0)                             # (G,)
-    alpha = jnp.exp(m - m_star[None])                       # (K, G)
-    l_star = jnp.sum(l * alpha, axis=0)                     # (G,)
-    out = jnp.sum(acc * alpha[..., None], axis=0)           # (G, D)
-    o_ref[0, 0] = (out / jnp.maximum(l_star, 1e-30)[:, None]).astype(o_ref.dtype)
-
-
-def decode_attention_splitk(
-    q: jax.Array,          # (B, Hq, D)
-    k_cache: jax.Array,    # (B, S, Hkv, D)
-    v_cache: jax.Array,
-    lengths: jax.Array,    # (B,) int32
-    *,
-    k_splits: int = 4,
-    block_k: int = 512,
-    softmax_scale=None,
-    interpret: bool = False,
-) -> jax.Array:
-    B, S, Hkv, D = k_cache.shape
-    Hq = q.shape[1]
-    G = Hq // Hkv
-    scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    assert S % k_splits == 0, (S, k_splits)
-    ck = S // k_splits                       # KV span per split chunk
-    bk = min(block_k, ck)
-    assert ck % bk == 0
-    nkc = ck // bk                           # tiles per chunk
-
-    qg = q.reshape(B, Hkv, G, D)
-    from repro.kernels.flash_attention.kernel import pltpu_vmem
-
-    partial_kernel = functools.partial(
-        _splitk_partial_kernel, bk=bk, nkc=nkc, scale=scale
-    )
-    m_p, l_p, acc_p = pl.pallas_call(
-        partial_kernel,
-        grid=(B, Hkv, k_splits, nkc),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, kc, kj: (b,)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, kc, kj: (b, h, 0, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, kc, kj: (b, kc * nkc + kj, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, kc, kj: (b, kc * nkc + kj, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, kc, kj: (b, h, kc, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, kc, kj: (b, h, kc, 0)),
-            pl.BlockSpec((1, 1, 1, G, D), lambda b, h, kc, kj: (b, h, kc, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, k_splits, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, k_splits, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, k_splits, G, D), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu_vmem((G,), jnp.float32),
-            pltpu_vmem((G,), jnp.float32),
-            pltpu_vmem((G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
-
-    out = pl.pallas_call(
-        _splitk_combine_kernel,
-        grid=(B, Hkv),
-        in_specs=[
-            pl.BlockSpec((1, 1, k_splits, G), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, k_splits, G), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, k_splits, G, D), lambda b, h: (b, h, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        interpret=interpret,
-    )(m_p, l_p, acc_p)
-    return out.reshape(B, Hq, D)
-
-
-# ---------------------------------------------------------------------------
-# paged flash decoding (block-table KV gather inside the grid)
-# ---------------------------------------------------------------------------
-
-
-def _paged_decode_kernel(
-    tbl_ref, len_ref,             # scalar-prefetch: (B,nb) tables, (B,) lens
-    q_ref, k_ref, v_ref, o_ref,   # (1,1,G,D), (1,ps,1,D), (1,ps,1,D), (1,1,G,D)
-    m_ref, l_ref, acc_ref,        # scratch (G,), (G,), (G,D)
-    *,
-    ps: int, nb: int, scale: float,
-):
-    """Single-stage paged kernel.  Grid (B, Hkv, nb): the innermost dim
-    walks the sequence's block table; the index_map has already DMA'd page
-    ``tbl_ref[b, j]`` into the (ps, D) KV tile, so the body is the same
-    online softmax as the contiguous kernel with j*ps as the tile origin."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    length = len_ref[b]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * ps < length)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                 # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (ps, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                           # (G, ps)
-        pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-
-    @pl.when(j == nb - 1)
-    def _finalize():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        ).astype(o_ref.dtype)
-
-
-def decode_attention_paged(
-    q: jax.Array,              # (B, Hq, D)
-    k_pages: jax.Array,        # (P, page_size, Hkv, D) shared pool
-    v_pages: jax.Array,
-    block_tables: jax.Array,   # (B, n_blocks) int32
-    lengths: jax.Array,        # (B,) int32
-    *,
-    softmax_scale=None,
-    interpret: bool = False,
-) -> jax.Array:
-    from jax.experimental.pallas import tpu as pltpu
-
-    P, ps, Hkv, D = k_pages.shape
-    B, nb = block_tables.shape
-    Hq = q.shape[1]
-    G = Hq // Hkv
-    scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-
-    qg = q.reshape(B, Hkv, G, D)
-    from repro.kernels.flash_attention.kernel import pltpu_vmem
-
-    kernel = functools.partial(_paged_decode_kernel, ps=ps, nb=nb, scale=scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, j, tbl, lens: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, j, tbl, lens: (tbl[b, j], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu_vmem((G,), jnp.float32),
-            pltpu_vmem((G,), jnp.float32),
-            pltpu_vmem((G, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qg, k_pages, v_pages)
-    return out.reshape(B, Hq, D)
-
-
-def _paged_splitk_partial_kernel(
-    tbl_ref, len_ref,             # scalar-prefetch
-    q_ref, k_ref, v_ref,          # (1,1,G,D), (1,ps,1,D), (1,ps,1,D)
-    m_out, l_out, acc_out,        # (1,1,1,G), (1,1,1,G), (1,1,1,G,D)
-    m_ref, l_ref, acc_ref,        # scratch
-    *,
-    ps: int, nbc: int, scale: float,
-):
-    """Stage 1 of paged split-K: grid (B, Hkv, K, nb/K); each chunk walks
-    its share of the block table and emits an unnormalized partial state
-    (identical contract to the contiguous split-K partial kernel)."""
-    b = pl.program_id(0)
-    kc = pl.program_id(2)
-    j = pl.program_id(3)
-    length = len_ref[b]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    tile_start = (kc * nbc + j) * ps
-
-    @pl.when(tile_start < length)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        pos = tile_start + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-
-    @pl.when(j == nbc - 1)
-    def _finalize():
-        m_out[0, 0, 0] = m_ref[...]
-        l_out[0, 0, 0] = l_ref[...]
-        acc_out[0, 0, 0] = acc_ref[...]
-
-
-def decode_attention_paged_splitk(
-    q: jax.Array,              # (B, Hq, D)
-    k_pages: jax.Array,        # (P, page_size, Hkv, D)
-    v_pages: jax.Array,
-    block_tables: jax.Array,   # (B, n_blocks) int32
-    lengths: jax.Array,        # (B,) int32
-    *,
-    k_splits: int = 4,
-    softmax_scale=None,
-    interpret: bool = False,
-) -> jax.Array:
-    """Two-stage paged flash decoding: the block-table axis is cut into
-    ``k_splits`` chunks (grid-parallel partial states), then merged with
-    the SAME combine kernel as the contiguous split-K path."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    P, ps, Hkv, D = k_pages.shape
-    B, nb = block_tables.shape
-    Hq = q.shape[1]
-    G = Hq // Hkv
-    scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    assert nb % k_splits == 0, (nb, k_splits)
-    nbc = nb // k_splits                     # pages per split chunk
-
-    qg = q.reshape(B, Hkv, G, D)
-    from repro.kernels.flash_attention.kernel import pltpu_vmem
-
-    partial_kernel = functools.partial(
-        _paged_splitk_partial_kernel, ps=ps, nbc=nbc, scale=scale
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, k_splits, nbc),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D),
-                         lambda b, h, kc, j, tbl, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, kc, j, tbl, lens: (tbl[b, kc * nbc + j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, kc, j, tbl, lens: (tbl[b, kc * nbc + j], 0, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G),
-                         lambda b, h, kc, j, tbl, lens: (b, h, kc, 0)),
-            pl.BlockSpec((1, 1, 1, G),
-                         lambda b, h, kc, j, tbl, lens: (b, h, kc, 0)),
-            pl.BlockSpec((1, 1, 1, G, D),
-                         lambda b, h, kc, j, tbl, lens: (b, h, kc, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu_vmem((G,), jnp.float32),
-            pltpu_vmem((G,), jnp.float32),
-            pltpu_vmem((G, D), jnp.float32),
-        ],
-    )
-    m_p, l_p, acc_p = pl.pallas_call(
-        partial_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, k_splits, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, k_splits, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, k_splits, G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qg, k_pages, v_pages)
-
-    out = pl.pallas_call(
-        _splitk_combine_kernel,
-        grid=(B, Hkv),
-        in_specs=[
-            pl.BlockSpec((1, 1, k_splits, G), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, k_splits, G), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, k_splits, G, D), lambda b, h: (b, h, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        interpret=interpret,
-    )(m_p, l_p, acc_p)
-    return out.reshape(B, Hq, D)
-
-
-# ---------------------------------------------------------------------------
-# mixed-batch chunked prefill (q-chunk flash decoding)
-# ---------------------------------------------------------------------------
-
-
-def _mixed_kernel(
-    len_ref,                      # (1,) int32 cached length for this b
-    q_ref, k_ref, v_ref, o_ref,   # (1,1,QG,D), (1,bk,1,D), (1,bk,1,D), (1,1,QG,D)
-    m_ref, l_ref, acc_ref,        # scratch (QG,), (QG,), (QG,D)
-    *,
-    bk: int, nk: int, G: int, Q: int, scale: float,
-):
-    """The decode kernel with a per-row causal limit: row r is query
-    ``r // G`` of the chunk, allowed keys ``< cache_len + r//G + 1``."""
-    kj = pl.program_id(2)
-    clen = len_ref[0]
+    refs: scalar prefetch ([tables,] cache_lens), q (Hkv, QG, D), k and v
+    (bk, Hkv, D) tiles, outputs (normalized (Hkv, QG, D), or the split-K
+    partials m, l (Hkv, QG, 1) and acc (Hkv, QG, D)), then scratch m, l,
+    acc of the same shapes as the partials.
+    """
+    len_ref = refs[n_prefetch - 1]
+    q_ref, k_ref, v_ref = refs[n_prefetch:n_prefetch + 3]
+    outs = refs[n_prefetch + 3:-3]
+    m_ref, l_ref, acc_ref = refs[-3:]
+    b, kc, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    clen = len_ref[b]
+    start = (kc * nkc + kj) * bk
 
     @pl.when(kj == 0)
     def _init():
@@ -547,34 +73,54 @@ def _mixed_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # the widest row sees clen + Q keys; tiles wholly past that are skipped
-    @pl.when(kj * bk < clen + Q)
+    @pl.when(start < clen + Q)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                 # (QG, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bk, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                           # (QG, bk)
-        pos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        row_q = jax.lax.broadcasted_iota(jnp.int32, (G * Q, 1), 0) // G
-        s = jnp.where(pos < clen + row_q + 1, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
+        pos = start - clen + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (Q * G, 1), 0)
+        # key at offset pos from the cache frontier is visible to row r iff
+        # pos <= r // G, i.e. G·pos <= r (no vector integer division)
+        visible = G * pos <= row                            # (QG, bk)
+        for h in range(q_ref.shape[0]):
+            q = q_ref[h].astype(jnp.float32)                # (QG, D)
+            k = k_ref[:, h, :].astype(jnp.float32)          # (bk, D)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                       # (QG, bk)
+            s = jnp.where(visible, s, NEG_INF)
+            m_prev = m_ref[h]                               # (QG, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[h] = m_new
+            pv = jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[:, h, :], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                               # (QG, D)
+            acc_ref[h] = acc_ref[h] * corr + pv
 
-    @pl.when(kj == nk - 1)
+    @pl.when(kj == nkc - 1)
     def _finalize():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        ).astype(o_ref.dtype)
+        if split:
+            # chunks entirely past the frontier emit the identity state
+            # (m=-inf, l=0, acc=0) — the combine's rescale zeroes them.
+            m_out, l_out, acc_out = outs
+            m_out[...] = m_ref[...]
+            l_out[...] = l_ref[...]
+            acc_out[...] = acc_ref[...]
+        else:
+            (o_ref,) = outs
+            o_ref[...] = (
+                acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+            ).astype(o_ref.dtype)
+
+
+def _combine(m, l, acc, dtype):
+    """Merge K split-K partial states (axis 1) by log-sum-exp rescaling."""
+    alpha = jnp.exp(m - jnp.max(m, axis=1, keepdims=True))  # (B, K, Hkv, QG, 1)
+    out = jnp.sum(acc * alpha, axis=1)
+    return (out / jnp.maximum(jnp.sum(l * alpha, axis=1), 1e-30)).astype(dtype)
 
 
 def _regroup_q_chunk(q: jax.Array, Hkv: int) -> jax.Array:
@@ -596,6 +142,147 @@ def _ungroup_q_chunk(out: jax.Array, Q: int, Hq: int) -> jax.Array:
                .reshape(B, Q, Hq, D))
 
 
+def _attend(q, k, v, cache_lens, tables, *, bk: int, k_splits: int,
+            softmax_scale, interpret: bool) -> jax.Array:
+    """q (B, Q, Hq, D) against contiguous k/v (B, S, Hkv, D) (``tables`` is
+    None) or a page pool (P, ps, Hkv, D) read through ``tables`` (B, nb)."""
+    B, Q, Hq, D = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    QG = Q * G
+    scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
+    n_tiles = tables.shape[1] if tables is not None else k.shape[1] // bk
+    assert n_tiles % k_splits == 0, (n_tiles, k_splits)
+    nkc = n_tiles // k_splits                   # tiles per split chunk
+
+    if tables is not None:
+        prefetch = (tables.astype(jnp.int32), cache_lens.astype(jnp.int32))
+
+        def kv_map(b, kc, kj, tbl, lens):
+            return (tbl[b, kc * nkc + kj], 0, 0, 0)
+    else:
+        prefetch = (cache_lens.astype(jnp.int32),)
+
+        def kv_map(b, kc, kj, lens):
+            return (b, kc * nkc + kj, 0, 0)
+
+    def q_map(b, kc, kj, *_):
+        return (b, 0, 0, 0)
+
+    def part_map(b, kc, kj, *_):
+        return (b, kc, 0, 0, 0)
+
+    split = k_splits > 1
+    if split:
+        out_specs = [pl.BlockSpec((None, None, Hkv, QG, 1), part_map)] * 2 + [
+            pl.BlockSpec((None, None, Hkv, QG, D), part_map)]
+        out_shape = [jax.ShapeDtypeStruct((B, k_splits, Hkv, QG, 1), jnp.float32)] * 2 + [
+            jax.ShapeDtypeStruct((B, k_splits, Hkv, QG, D), jnp.float32)]
+    else:
+        out_specs = pl.BlockSpec((None, Hkv, QG, D), q_map)
+        out_shape = jax.ShapeDtypeStruct((B, Hkv, QG, D), q.dtype)
+    kv_spec = pl.BlockSpec((None, bk, Hkv, D), kv_map)
+    kernel = functools.partial(
+        _attend_kernel, n_prefetch=len(prefetch), bk=bk, nkc=nkc, Q=Q, G=G,
+        scale=scale, split=split,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(B, k_splits, nkc),
+            in_specs=[pl.BlockSpec((None, Hkv, QG, D), q_map), kv_spec, kv_spec],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((Hkv, QG, 1), jnp.float32),
+                pltpu.VMEM((Hkv, QG, 1), jnp.float32),
+                pltpu.VMEM((Hkv, QG, D), jnp.float32),
+            ],
+        ),
+        out_shape=out_shape,
+        interpret=interpret,
+    )(*prefetch, _regroup_q_chunk(q, Hkv), k, v)
+    if split:
+        out = _combine(*out, q.dtype)
+    return _ungroup_q_chunk(out, Q, Hq)
+
+
+def decode_attention_pallas(
+    q: jax.Array,          # (B, Hq, D)
+    k_cache: jax.Array,    # (B, S, Hkv, D)
+    v_cache: jax.Array,
+    lengths: jax.Array,    # (B,) int32
+    *,
+    block_k: int = 512,
+    softmax_scale=None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Single-stage flash decoding: each sequence walks its KV tiles."""
+    return decode_attention_splitk(
+        q, k_cache, v_cache, lengths, k_splits=1, block_k=block_k,
+        softmax_scale=softmax_scale, interpret=interpret,
+    )
+
+
+def decode_attention_splitk(
+    q: jax.Array,          # (B, Hq, D)
+    k_cache: jax.Array,    # (B, S, Hkv, D)
+    v_cache: jax.Array,
+    lengths: jax.Array,    # (B,) int32
+    *,
+    k_splits: int = 4,
+    block_k: int = 512,
+    softmax_scale=None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Two-stage split-K flash decoding over a contiguous cache."""
+    S = k_cache.shape[1]
+    assert S % k_splits == 0, (S, k_splits)
+    # the largest tile up to block_k that divides a split chunk
+    bk = math.gcd(block_k, S // k_splits)
+    out = _attend(q[:, None], k_cache, v_cache, lengths - 1, None, bk=bk,
+                  k_splits=k_splits, softmax_scale=softmax_scale,
+                  interpret=interpret)
+    return out[:, 0]
+
+
+def decode_attention_paged(
+    q: jax.Array,              # (B, Hq, D)
+    k_pages: jax.Array,        # (P, page_size, Hkv, D) shared pool
+    v_pages: jax.Array,
+    block_tables: jax.Array,   # (B, n_blocks) int32
+    lengths: jax.Array,        # (B,) int32
+    *,
+    softmax_scale=None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Single-stage paged flash decoding: one grid step per page."""
+    return decode_attention_paged_splitk(
+        q, k_pages, v_pages, block_tables, lengths, k_splits=1,
+        softmax_scale=softmax_scale, interpret=interpret,
+    )
+
+
+def decode_attention_paged_splitk(
+    q: jax.Array,              # (B, Hq, D)
+    k_pages: jax.Array,        # (P, page_size, Hkv, D)
+    v_pages: jax.Array,
+    block_tables: jax.Array,   # (B, n_blocks) int32
+    lengths: jax.Array,        # (B,) int32
+    *,
+    k_splits: int = 4,
+    softmax_scale=None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Two-stage paged flash decoding: the block-table axis is cut into
+    ``k_splits`` chunks (grid-parallel partial states), then merged with
+    the same combine as the contiguous split-K path."""
+    out = _attend(q[:, None], k_pages, v_pages, lengths - 1, block_tables,
+                  bk=k_pages.shape[1], k_splits=k_splits,
+                  softmax_scale=softmax_scale, interpret=interpret)
+    return out[:, 0]
+
+
 def mixed_attention_pallas(
     q: jax.Array,          # (B, Q, Hq, D) — Q new tokens per sequence
     k_cache: jax.Array,    # (B, S, Hkv, D), chunk KV already written
@@ -606,87 +293,9 @@ def mixed_attention_pallas(
     softmax_scale=None,
     interpret: bool = False,
 ) -> jax.Array:
-    B, S, Hkv, D = k_cache.shape
-    Q, Hq = q.shape[1], q.shape[2]
-    G = Hq // Hkv
-    scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-    bk = min(block_k, S)
-    assert S % bk == 0
-    nk = S // bk
-
-    qg = _regroup_q_chunk(q, Hkv)
-    kernel = functools.partial(_mixed_kernel, bk=bk, nk=nk, G=G, Q=Q,
-                               scale=scale)
-    from repro.kernels.flash_attention.kernel import pltpu_vmem
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(B, Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, kj: (b,)),
-            pl.BlockSpec((1, 1, Q * G, D), lambda b, h, kj: (b, h, 0, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, kj: (b, kj, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, kj: (b, kj, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Q * G, D), lambda b, h, kj: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, Q * G, D), q.dtype),
-        scratch_shapes=[
-            pltpu_vmem((Q * G,), jnp.float32),
-            pltpu_vmem((Q * G,), jnp.float32),
-            pltpu_vmem((Q * G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(cache_lens.astype(jnp.int32), qg, k_cache, v_cache)
-    return _ungroup_q_chunk(out, Q, Hq)
-
-
-def _mixed_paged_kernel(
-    tbl_ref, len_ref,             # scalar-prefetch: (B,nb) tables, (B,) lens
-    q_ref, k_ref, v_ref, o_ref,   # (1,1,QG,D), (1,ps,1,D), (1,ps,1,D), (1,1,QG,D)
-    m_ref, l_ref, acc_ref,        # scratch (QG,), (QG,), (QG,D)
-    *,
-    ps: int, nb: int, G: int, Q: int, scale: float,
-):
-    """Paged q-chunk kernel: grid (B, Hkv, nb) walking the block table with
-    the per-row causal limit of ``_mixed_kernel``."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    clen = len_ref[b]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * ps < clen + Q)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                 # (QG, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (ps, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                           # (QG, ps)
-        pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        row_q = jax.lax.broadcasted_iota(jnp.int32, (G * Q, 1), 0) // G
-        s = jnp.where(pos < clen + row_q + 1, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-
-    @pl.when(j == nb - 1)
-    def _finalize():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        ).astype(o_ref.dtype)
+    bk = math.gcd(block_k, k_cache.shape[1])
+    return _attend(q, k_cache, v_cache, cache_lens, None, bk=bk, k_splits=1,
+                   softmax_scale=softmax_scale, interpret=interpret)
 
 
 def mixed_attention_paged(
@@ -699,43 +308,6 @@ def mixed_attention_paged(
     softmax_scale=None,
     interpret: bool = False,
 ) -> jax.Array:
-    from jax.experimental.pallas import tpu as pltpu
-
-    P, ps, Hkv, D = k_pages.shape
-    B, nb = block_tables.shape
-    Q, Hq = q.shape[1], q.shape[2]
-    G = Hq // Hkv
-    scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
-
-    qg = _regroup_q_chunk(q, Hkv)                           # (B, Hkv, QG, D)
-    from repro.kernels.flash_attention.kernel import pltpu_vmem
-
-    kernel = functools.partial(_mixed_paged_kernel, ps=ps, nb=nb, G=G, Q=Q,
-                               scale=scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, Q * G, D),
-                         lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, j, tbl, lens: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, j, tbl, lens: (tbl[b, j], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Q * G, D),
-                               lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu_vmem((Q * G,), jnp.float32),
-            pltpu_vmem((Q * G,), jnp.float32),
-            pltpu_vmem((Q * G, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, Q * G, D), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), cache_lens.astype(jnp.int32),
-      qg, k_pages, v_pages)
-    return _ungroup_q_chunk(out, Q, Hq)
+    return _attend(q, k_pages, v_pages, cache_lens, block_tables,
+                   bk=k_pages.shape[1], k_splits=1,
+                   softmax_scale=softmax_scale, interpret=interpret)

@@ -17,6 +17,7 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret as _interpret
 from repro.kernels.decode_attention.kernel import (
     decode_attention_paged,
     decode_attention_paged_splitk,
@@ -33,10 +34,6 @@ SPLITK_MAX = 8
 # spend their grid cells on softmax-state bookkeeping instead of KV reads
 # (the paged 4k bench regressed to 0.88x vs contiguous before this floor)
 SPLITK_MIN_CHUNK = 256
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def auto_k_splits(S: int, block_k: int = 512) -> int:

@@ -25,6 +25,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -141,9 +142,9 @@ def flash_attention_pallas(
         out_specs=pl.BlockSpec((1, G, bq, D), lambda bh, qi, kj: (bh, 0, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, Sq, D), q.dtype),
         scratch_shapes=[
-            pltpu_vmem((G, bq), jnp.float32),
-            pltpu_vmem((G, bq), jnp.float32),
-            pltpu_vmem((G, bq, D), jnp.float32),
+            pltpu.VMEM((G, bq), jnp.float32),
+            pltpu.VMEM((G, bq), jnp.float32),
+            pltpu.VMEM((G, bq, D), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf)
@@ -151,10 +152,3 @@ def flash_attention_pallas(
     return out.reshape(B, Hkv, G, Sq, D).transpose(0, 3, 1, 2, 4).reshape(
         B, Sq, Hq, D
     )
-
-
-def pltpu_vmem(shape, dtype):
-    """VMEM scratch allocator (portable import point for interpret mode)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)

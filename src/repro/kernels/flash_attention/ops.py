@@ -14,11 +14,8 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret as _interpret
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @lru_cache(maxsize=None)

@@ -5,11 +5,8 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret as _interpret
 from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("eps", "block_rows"))

@@ -18,8 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.flash_attention.kernel import pltpu_vmem
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _wkv6_kernel(
@@ -114,7 +113,7 @@ def wkv6_pallas(
             jax.ShapeDtypeStruct((B * H, S, N), jnp.float32),
             jax.ShapeDtypeStruct((B * H, N, N), jnp.float32),
         ],
-        scratch_shapes=[pltpu_vmem((N, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
     )(rf, kf, vf, wf, uf, s0)
     return (

@@ -7,11 +7,8 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret as _interpret
 from repro.kernels.rwkv6_scan.kernel import wkv6_pallas
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @lru_cache(maxsize=None)

@@ -16,8 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.flash_attention.kernel import pltpu_vmem
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(
@@ -116,7 +115,7 @@ def ssd_pallas(
             jax.ShapeDtypeStruct((B * H, S, P), jnp.float32),
             jax.ShapeDtypeStruct((B * H, P, N), jnp.float32),
         ],
-        scratch_shapes=[pltpu_vmem((P, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
     )(xf, dtf, af, Bm, Cm, s0)
     return (

@@ -6,11 +6,8 @@ from functools import lru_cache
 
 import jax
 
+from repro.kernels import interpret as _interpret
 from repro.kernels.ssd_scan.kernel import ssd_pallas
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @lru_cache(maxsize=None)

@@ -7,26 +7,14 @@ set XLA_FLAGS before any jax initialization.
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n: int) -> dict:
-    """Version-gated ``axis_types`` for ``jax.make_mesh``.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg) first appeared
-    after jax 0.4.x; on older versions every mesh axis is implicitly Auto,
-    so omitting the kwarg is behavior-identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: int = 0):
@@ -34,6 +22,6 @@ def make_host_mesh(data: int = 2, model: int = 2, pod: int = 0):
     --xla_force_host_platform_device_count)."""
     if pod:
         return jax.make_mesh(
-            (pod, data, model), ("pod", "data", "model"), **_axis_type_kwargs(3)
+            (pod, data, model), ("pod", "data", "model"), axis_types=(AxisType.Auto,) * 3
         )
-    return jax.make_mesh((data, model), ("data", "model"), **_axis_type_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2)

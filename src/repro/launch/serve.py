@@ -2,9 +2,10 @@
 
 Deployment units are (arch × tier × mode) triplets; their T_i/L_i profiles
 come either from the paper's Table 1 (--paper-dus) or from roofline-derived
-service rates of the dry-run artifacts (--roofline-dus).  A reduced-config
-ServingEngine executes real decode steps for the traffic the router sends,
-while the simulator supplies demand, capacity events, and autoscaling.
+service rates of the dry-run artifacts (--roofline-dus).  A ServingEngine
+(reduced config, or the published one with --full-width) executes real
+decode steps for the traffic the router sends, while the simulator
+supplies demand, capacity events, and autoscaling.
 
     PYTHONPATH=src python -m repro.launch.serve --duration 600 \
         --demand 400 --outage 200:400 --arch qwen3-0.6b
@@ -98,6 +99,9 @@ def main(argv=None):
     ap.add_argument("--trace-out", default="",
                     help="--fleet: write the flight-recorder event trace "
                          "(JSONL) here after the run")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the arch's published config (full depth and "
+                         "width, bf16) instead of the reduced smoke model")
     ap.add_argument("--quiet", action="store_true",
                     help="suppress informational output")
     args = ap.parse_args(argv)
@@ -116,7 +120,7 @@ def main(argv=None):
             outage = (s, e)
         rt = build_demo_fleet(arch=args.arch, n_requests=args.requests,
                               rate=max(args.demand / 100.0, 1.0),
-                              outage=outage)
+                              outage=outage, reduced=not args.full_width)
         # the streaming client API: every trace request becomes a live
         # RequestHandle (status / tokens() / cancel()), and TTFT is
         # observed at the first emitted token instead of inferred later
@@ -178,7 +182,9 @@ def main(argv=None):
         from repro.models import Model
         from repro.serving import EngineConfig, ServingEngine
 
-        cfg = get_config(args.arch).reduce()
+        cfg = get_config(args.arch)
+        if not args.full_width:
+            cfg = cfg.reduce()
         model = Model(cfg)
         params = model.init(jax.random.key(0))
         eng = ServingEngine(model, params, EngineConfig(max_len=64, decode_batch=4))
@@ -213,7 +219,7 @@ def main(argv=None):
             toks = eng.generate(prompt, steps=args.execute_samples, prompt_len=16)
             dt = time.perf_counter() - t0
             print(f"executed {toks.size} real decode tokens on replica engine "
-                  f"(reduced {args.arch}, {toks.size / dt:.1f} tok/s warm); "
+                  f"({cfg.name}, {toks.size / dt:.1f} tok/s warm); "
                   f"sample: {toks[0].tolist()}")
     return log
 

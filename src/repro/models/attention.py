@@ -274,11 +274,11 @@ def attention_decode(
 
     # ring buffer already bounds the SWA window, so only length masking
     # remains — which is exactly the flash-decoding kernel's contract.
-    if cfg.use_pallas and W % min(512, W) == 0:
+    if cfg.use_pallas:
         from repro.kernels.decode_attention.ops import decode_attention as kdecode
 
         lengths = eff_len if ragged else jnp.broadcast_to(eff_len, (B,))
-        out = kdecode(q[:, 0], k_c, v_c, lengths)
+        out = kdecode(q[:, 0], k_c, v_c, lengths, block_k=math.gcd(W, 512))
     else:
         out = layers.decode_attention(q[:, 0], k_c, v_c, eff_len, window=0)
     out = jnp.einsum("bq,qd->bd", out.reshape(B, cfg.q_dim), p["wo"])[:, None, :]
@@ -350,8 +350,8 @@ def attention_mixed(
     positions project/attend (padding rows compute discarded garbage, which
     is what lets ONE trace per pow-of-2 Q bucket serve every chunk shape);
     only rows ``i < new_lens[b]`` write KV — padding writes are suppressed
-    (contiguous: the write is a positional select, so only in-range rows
-    land; paged: redirected to the trash page), so garbage never lands
+    (contiguous: scattered past the cache and dropped; paged: redirected
+    to the trash page), so garbage never lands
     where real KV will live before it is overwritten.  Query i attends
     causally to every position ``<= cache_lens[b] + i`` (cached prefix +
     the chunk's earlier tokens).
@@ -388,24 +388,15 @@ def attention_mixed(
         k_c = cache.k.at[page, row].set(k_new.astype(cache.k.dtype))
         v_c = cache.v.at[page, row].set(v_new.astype(cache.v.dtype))
     else:
-        # positional select instead of scatter: for every cache position,
-        # either the chunk row that lands there or the existing entry.
-        # Measurably cheaper than a scatter on CPU backends, and padding
-        # rows (offset >= new_len) are suppressed by construction.
+        # one (Hkv, Dh) row scatter per chunk row; padding rows aim past
+        # the cache and are dropped.  (A positional select over every cache
+        # position, cheaper on CPU, lowers on TPU to a per-element gather
+        # of the whole cache in every layer.)
         S = cache.k.shape[1]
-        off = jnp.arange(S, dtype=jnp.int32)[None, :] - cache_lens[:, None]
-        wmask = (off >= 0) & (off < new_lens[:, None])   # (B, S)
-        idx = jnp.clip(off, 0, Q - 1)[:, :, None, None]
-
-        def write(c, n):
-            g = jnp.take_along_axis(
-                n.astype(c.dtype),
-                jnp.broadcast_to(idx, (B, S, *c.shape[2:])), axis=1,
-            )
-            return jnp.where(wmask[:, :, None, None], g, c)
-
-        k_c = write(cache.k, k_new)
-        v_c = write(cache.v, v_new)
+        pos = jnp.where(valid, positions, S)
+        slot = jnp.arange(B)[:, None]
+        k_c = cache.k.at[slot, pos].set(k_new.astype(cache.k.dtype), mode="drop")
+        v_c = cache.v.at[slot, pos].set(v_new.astype(cache.v.dtype), mode="drop")
 
     if page_table is not None and attn_window is not None:
         ps = cache.k.shape[1]

@@ -261,9 +261,7 @@ def _moe_sharded(p, xf, cfg: ModelConfig, mesh) -> Tuple[jax.Array, jax.Array]:
             aux = lax.pmean(aux, a)
         return y, aux
 
-    from repro.jax_compat import shard_map
-
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, P(None, None), wg_spec, wg_spec, wd_spec),
