@@ -75,6 +75,10 @@ class Replica:
     def warm(self) -> None:
         assert self.state == ReplicaState.PROVISIONING, self.state
         self.session = self.engine.new_session()
+        # the session's pump spans and req.admitted land in this replica's
+        # flight recorder, tagged like the replica's own events
+        self.session.tracer = self.tracer
+        self.session.trace_tags = {"replica": self.name, "tier": self.tier}
         if self._spec_k_cmd is not None:
             # the controller commanded a depth before this session existed
             # (tick 0, or a replica provisioned mid-run): a session born

@@ -52,7 +52,7 @@ from repro.fleet.replica import Replica, ReplicaState
 from repro.fleet.telemetry import Ewma, TelemetryBus
 from repro.fleet.workload import Request
 from repro.obs import DecisionRecord, Tracer
-from repro.serving.engine import EngineConfig, ServingEngine
+from repro.serving.engine import EngineConfig, PumpReport, ServingEngine
 
 logger = logging.getLogger(__name__)
 
@@ -800,8 +800,33 @@ class FleetRuntime:
 
     # -- one tick ------------------------------------------------------------
     def tick(self) -> None:
-        t, cfg = self.t, self.cfg
+        """One control-loop tick, each phase in a span on the tracer:
+        ``fleet.tick`` holds ``fleet.intake`` (arrivals), ``fleet.control``
+        (failures, capacity, the controller and its knobs),
+        ``fleet.dispatch``, then per replica its ``engine.pump`` and
+        ``fleet.deliver`` (its tokens and completions to the sinks), and
+        ``fleet.autoscale`` (telemetry roll, autoscaling, the tick's
+        metrics)."""
+        t, tr = self.t, self.tracer
+        with tr.begin("fleet.tick"):
+            with tr.begin("fleet.intake"):
+                demand = self._intake(t)
+            with tr.begin("fleet.control"):
+                decision, measured = self._control(t, demand)
+            with tr.begin("fleet.dispatch"):
+                self._dispatch(t, decision)
+            tally = self._pump_replicas(t)
+            with tr.begin("fleet.autoscale"):
+                self.telemetry.roll(self.cfg.tick_s)
+                self._autoscale(t, decision, demand, measured)
+                self._record_tick(t, demand, decision, *tally)
+        self.t += self.cfg.tick_s
+        self.ticks += 1
 
+    def _intake(self, t: float) -> float:
+        """Arrivals into the dispatcher backlog; returns the demand signal
+        the controller sees."""
+        cfg = self.cfg
         # 1. arrivals (trace requests due now + open-loop submissions)
         arrived: List[Request] = []
         while (self._wl_idx < len(self.workload)
@@ -853,7 +878,12 @@ class FleetRuntime:
         self._requeue_pressure = 0.0
         if self.kv_store is not None:
             demand += recovery
+        return demand
 
+    def _control(self, t: float, demand: float):
+        """Failures, preemptions, capacity and the controller's step with
+        the knobs it drives; returns (decision, measured t_max)."""
+        cfg = self.cfg
         # 2. failure injections (crashes: pool ceiling unchanged)
         while self.failures and self.failures[0].t <= t:
             ev = self.failures.pop(0)
@@ -1010,7 +1040,9 @@ class FleetRuntime:
                                  if accept is not None else None))
             for rep in self.replicas[spec.name]:
                 rep.set_speculation(k)
+        return decision, measured
 
+    def _dispatch(self, t: float, decision) -> None:
         # 5. request-granularity dispatch
         self.dispatcher.dispatch(decision.weights, self.replicas, now=t)
         # requests the dispatcher dropped as unfittable (they fit no live
@@ -1026,7 +1058,12 @@ class FleetRuntime:
                 for sink in self._sinks:
                     sink.on_drop(req.rid, t, reason)
 
-        # 6. pump every live replica one admission+chunk cycle
+    def _pump_replicas(self, t: float):
+        """Pump every live replica once; returns the tick's per-tier
+        (completions, latency sums, occupancy sums, pumps counted)."""
+        cfg = self.cfg
+        # 6. pump every live replica one admission+chunk cycle (each pump
+        # is an engine.pump span, opened by the replica's session)
         completions_per_tier = {s.name: 0 for s in self.tiers}
         latency_sum = {s.name: 0.0 for s in self.tiers}
         occ_sum = {s.name: 0.0 for s in self.tiers}
@@ -1052,48 +1089,52 @@ class FleetRuntime:
                     self._flush_replica(spec.name, rep)
                 if report is None:
                     continue
-                self._pump_wall_s += report.wall_s
-                self._useful_tokens += report.useful_tokens
-                self._wasted_tokens += report.wasted_tokens
-                self.tracer.event("engine.pump", cat="engine", sampled=True,
-                                  replica=rep.name, tier=spec.name,
-                                  wall_s=report.wall_s,
-                                  admit_s=report.admit_s,
-                                  dispatch_s=report.dispatch_s,
-                                  sync_s=report.sync_s,
-                                  occupancy=report.occupancy,
-                                  completed=len(report.completed))
-                if getattr(report, "spec_rounds", 0):
-                    # speculation audit rides next to the pump it happened
-                    # in: drafted/accepted per replica-tick is the raw
-                    # series behind the tier acceptance EWMA
-                    self.tracer.event("engine.speculate", cat="engine",
-                                      sampled=True, replica=rep.name,
-                                      tier=spec.name,
-                                      drafted=report.drafted_tokens,
-                                      accepted=report.accepted_tokens,
-                                      rounds=report.spec_rounds)
-                qd = rep.load
-                self.telemetry.record_pump(spec.name, rep.name, report, qd)
                 if rep.state == ReplicaState.READY:
                     occ_sum[spec.name] += report.occupancy
                     occ_n[spec.name] += 1
-                for rid, toks in report.tokens.items():
-                    # the TRUE first-token stamp: the tick the token was
-                    # actually emitted, not inferred from the completion
-                    if rid not in self._first_token_t:
-                        self._first_token_t[rid] = t + cfg.tick_s
-                        self.tracer.event("req.first_token",
-                                          t=t + cfg.tick_s, cat="req",
-                                          rid=rid, replica=rep.name,
-                                          tier=spec.name)
-                    for sink in self._sinks:
-                        sink.on_tokens(rid, toks, rep.name, t + cfg.tick_s)
-                for rid, toks in report.completed.items():
-                    self._complete(rid, toks, rep, spec,
-                                   completions_per_tier, latency_sum)
-        self.telemetry.roll(cfg.tick_s)
+                with self.tracer.begin("fleet.deliver"):
+                    self._deliver(t, spec, rep, report, completions_per_tier,
+                                  latency_sum)
+        return completions_per_tier, latency_sum, occ_sum, occ_n
 
+    def _deliver(self, t: float, spec: TierSpec, rep: Replica,
+                 report: PumpReport, completions_per_tier: Dict[str, int],
+                 latency_sum: Dict[str, float]) -> None:
+        """Fold one pump's report into the fleet's counters and hand its
+        tokens and completions to the sinks."""
+        cfg = self.cfg
+        self._pump_wall_s += report.wall_s
+        self._useful_tokens += report.useful_tokens
+        self._wasted_tokens += report.wasted_tokens
+        if getattr(report, "spec_rounds", 0):
+            # speculation audit rides next to the pump it happened
+            # in: drafted/accepted per replica-tick is the raw
+            # series behind the tier acceptance EWMA
+            self.tracer.event("engine.speculate", cat="engine",
+                              sampled=True, replica=rep.name,
+                              tier=spec.name,
+                              drafted=report.drafted_tokens,
+                              accepted=report.accepted_tokens,
+                              rounds=report.spec_rounds)
+        qd = rep.load
+        self.telemetry.record_pump(spec.name, rep.name, report, qd)
+        for rid, toks in report.tokens.items():
+            # the TRUE first-token stamp: the tick the token was
+            # actually emitted, not inferred from the completion
+            if rid not in self._first_token_t:
+                self._first_token_t[rid] = t + cfg.tick_s
+                self.tracer.event("req.first_token",
+                                  t=t + cfg.tick_s, cat="req",
+                                  rid=rid, replica=rep.name,
+                                  tier=spec.name)
+            for sink in self._sinks:
+                sink.on_tokens(rid, toks, rep.name, t + cfg.tick_s)
+        for rid, toks in report.completed.items():
+            self._complete(rid, toks, rep, spec,
+                           completions_per_tier, latency_sum)
+
+    def _autoscale(self, t: float, decision, demand: float, measured) -> None:
+        cfg = self.cfg
         # 7. autoscaling toward the weighted share of measured demand — or,
         # in the forecast arm, of the seasonal prediction read one
         # provisioning-lag ahead (so replicas are READY when the ramp
@@ -1166,6 +1207,11 @@ class FleetRuntime:
                                   warm=int(pool.warm),
                                   warm_inflight=int(pool.warm_inflight))
 
+    def _record_tick(self, t: float, demand: float, decision,
+                     completions_per_tier: Dict[str, int],
+                     latency_sum: Dict[str, float],
+                     occ_sum: Dict[str, float], occ_n: Dict[str, int]) -> None:
+        cfg = self.cfg
         # 8. metrics
         names = [s.name for s in self.tiers]
         ready = np.array([sum(1 for r in self.replicas[n]
@@ -1192,8 +1238,6 @@ class FleetRuntime:
             dropped_rps=0.0, latency_s=lat, utilization=util,
             cost_rate=cost_rate,
         ))
-        self.t += cfg.tick_s
-        self.ticks += 1
 
     def _trade_capacity(self, t: float, wants: Dict[str, int]) -> None:
         """Cross-model capacity trading: lease pool-ceiling units from a

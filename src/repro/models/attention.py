@@ -261,16 +261,17 @@ def attention_decode(
     else:
         write_at = cache_len
         eff_len = cache_len + 1
-    if ragged:
-        k_c = jax.vmap(
-            lambda c, n, w: lax.dynamic_update_slice(c, n, (w, 0, 0))
-        )(cache.k, k_new, write_at)
-        v_c = jax.vmap(
-            lambda c, n, w: lax.dynamic_update_slice(c, n, (w, 0, 0))
-        )(cache.v, v_new, write_at)
-    else:
-        k_c = lax.dynamic_update_slice(cache.k, k_new, (0, write_at, 0, 0))
-        v_c = lax.dynamic_update_slice(cache.v, v_new, (0, write_at, 0, 0))
+    with jax.named_scope("kv_write"):
+        if ragged:
+            k_c = jax.vmap(
+                lambda c, n, w: lax.dynamic_update_slice(c, n, (w, 0, 0))
+            )(cache.k, k_new, write_at)
+            v_c = jax.vmap(
+                lambda c, n, w: lax.dynamic_update_slice(c, n, (w, 0, 0))
+            )(cache.v, v_new, write_at)
+        else:
+            k_c = lax.dynamic_update_slice(cache.k, k_new, (0, write_at, 0, 0))
+            v_c = lax.dynamic_update_slice(cache.v, v_new, (0, write_at, 0, 0))
 
     # ring buffer already bounds the SWA window, so only length masking
     # remains — which is exactly the flash-decoding kernel's contract.
@@ -311,8 +312,9 @@ def _attention_decode_paged(
         page_table, (cache_len // ps)[:, None], axis=1
     )[:, 0]
     row = cache_len % ps
-    k_c = cache.k.at[page, row].set(k_new[:, 0])
-    v_c = cache.v.at[page, row].set(v_new[:, 0])
+    with jax.named_scope("kv_write"):
+        k_c = cache.k.at[page, row].set(k_new[:, 0])
+        v_c = cache.v.at[page, row].set(v_new[:, 0])
     eff_len = cache_len + 1
 
     if cfg.use_pallas:
@@ -385,8 +387,9 @@ def attention_mixed(
         page = jnp.take_along_axis(page_table, block, axis=1)
         page = jnp.where(valid, page, 0)                 # padding -> trash page
         row = positions % ps
-        k_c = cache.k.at[page, row].set(k_new.astype(cache.k.dtype))
-        v_c = cache.v.at[page, row].set(v_new.astype(cache.v.dtype))
+        with jax.named_scope("kv_write"):
+            k_c = cache.k.at[page, row].set(k_new.astype(cache.k.dtype))
+            v_c = cache.v.at[page, row].set(v_new.astype(cache.v.dtype))
     else:
         # one (Hkv, Dh) row scatter per chunk row; padding rows aim past
         # the cache and are dropped.  (A positional select over every cache
@@ -395,8 +398,9 @@ def attention_mixed(
         S = cache.k.shape[1]
         pos = jnp.where(valid, positions, S)
         slot = jnp.arange(B)[:, None]
-        k_c = cache.k.at[slot, pos].set(k_new.astype(cache.k.dtype), mode="drop")
-        v_c = cache.v.at[slot, pos].set(v_new.astype(cache.v.dtype), mode="drop")
+        with jax.named_scope("kv_write"):
+            k_c = cache.k.at[slot, pos].set(k_new.astype(cache.k.dtype), mode="drop")
+            v_c = cache.v.at[slot, pos].set(v_new.astype(cache.v.dtype), mode="drop")
 
     if page_table is not None and attn_window is not None:
         ps = cache.k.shape[1]

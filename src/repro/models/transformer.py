@@ -222,12 +222,13 @@ def fused_decode_weights(params: Dict, cfg: ModelConfig):
     None — fine for single-step callers) re-materializes the concatenated
     matrices every token whenever the layer scan is a real while loop,
     which measurably costs decode throughput."""
-    wqkv = attention.fuse_qkv_weights(params["layers"]["attn"])
-    w_gu = None
-    if not cfg.is_moe and cfg.mlp_type != "gelu":
-        w_gu = layers.fuse_gate_up_weights(
-            params["layers"]["mlp"]["w_gate"], params["layers"]["mlp"]["w_up"]
-        )
+    with jax.named_scope("fuse_weights"):
+        wqkv = attention.fuse_qkv_weights(params["layers"]["attn"])
+        w_gu = None
+        if not cfg.is_moe and cfg.mlp_type != "gelu":
+            w_gu = layers.fuse_gate_up_weights(
+                params["layers"]["mlp"]["w_gate"], params["layers"]["mlp"]["w_up"]
+            )
     return {"wqkv": wqkv, "w_gu": w_gu}
 
 
@@ -253,20 +254,22 @@ def run_layers_decode(
     def body(x, inputs):
         lp, ck, cv, wqkv_l, wgu_l = inputs
         h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, new_cache = attention.attention_decode(
-            lp["attn"], h, attention.KVCache(k=ck, v=cv), cache_len, cfg,
-            wqkv=wqkv_l, page_table=page_table,
-        )
+        with jax.named_scope("attn"):
+            a, new_cache = attention.attention_decode(
+                lp["attn"], h, attention.KVCache(k=ck, v=cv), cache_len, cfg,
+                wqkv=wqkv_l, page_table=page_table,
+            )
         x = x + a
         h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.is_moe:
-            m, _ = moe.moe_block(lp["moe"], h, cfg, mesh)
-        elif cfg.mlp_type == "gelu":
-            hu = jnp.einsum("...d,df->...f", h, lp["mlp"]["w_up"])
-            hu = jax.nn.gelu(hu.astype(jnp.float32)).astype(h.dtype)
-            m = jnp.einsum("...f,fd->...d", hu, lp["mlp"]["w_down"])
-        else:
-            m = layers.swiglu_fused(h, wgu_l, lp["mlp"]["w_down"])
+        with jax.named_scope("mlp"):
+            if cfg.is_moe:
+                m, _ = moe.moe_block(lp["moe"], h, cfg, mesh)
+            elif cfg.mlp_type == "gelu":
+                hu = jnp.einsum("...d,df->...f", h, lp["mlp"]["w_up"])
+                hu = jax.nn.gelu(hu.astype(jnp.float32)).astype(h.dtype)
+                m = jnp.einsum("...f,fd->...d", hu, lp["mlp"]["w_down"])
+            else:
+                m = layers.swiglu_fused(h, wgu_l, lp["mlp"]["w_down"])
         x = x + m
         return x, (new_cache.k, new_cache.v)
 
@@ -306,21 +309,23 @@ def run_layers_mixed(
     def body(x, inputs):
         lp, ck, cv, wqkv_l, wgu_l = inputs
         h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, new_cache = attention.attention_mixed(
-            lp["attn"], h, attention.KVCache(k=ck, v=cv), cache_lens,
-            new_lens, cfg, wqkv=wqkv_l, page_table=page_table,
-            attn_window=attn_window,
-        )
+        with jax.named_scope("attn"):
+            a, new_cache = attention.attention_mixed(
+                lp["attn"], h, attention.KVCache(k=ck, v=cv), cache_lens,
+                new_lens, cfg, wqkv=wqkv_l, page_table=page_table,
+                attn_window=attn_window,
+            )
         x = x + a
         h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.is_moe:
-            m, _ = moe.moe_block(lp["moe"], h, cfg, mesh)
-        elif cfg.mlp_type == "gelu":
-            hu = jnp.einsum("...d,df->...f", h, lp["mlp"]["w_up"])
-            hu = jax.nn.gelu(hu.astype(jnp.float32)).astype(h.dtype)
-            m = jnp.einsum("...f,fd->...d", hu, lp["mlp"]["w_down"])
-        else:
-            m = layers.swiglu_fused(h, wgu_l, lp["mlp"]["w_down"])
+        with jax.named_scope("mlp"):
+            if cfg.is_moe:
+                m, _ = moe.moe_block(lp["moe"], h, cfg, mesh)
+            elif cfg.mlp_type == "gelu":
+                hu = jnp.einsum("...d,df->...f", h, lp["mlp"]["w_up"])
+                hu = jax.nn.gelu(hu.astype(jnp.float32)).astype(h.dtype)
+                m = jnp.einsum("...f,fd->...d", hu, lp["mlp"]["w_down"])
+            else:
+                m = layers.swiglu_fused(h, wgu_l, lp["mlp"]["w_down"])
         x = x + m
         return x, (new_cache.k, new_cache.v)
 
@@ -372,12 +377,13 @@ def run_layers_prefill_paged(
 def logits_from_hidden(
     params: Dict, x: jax.Array, cfg: ModelConfig, mesh=None
 ) -> jax.Array:
-    x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.family == "encoder":
-        w = params["head"]
-    else:
-        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, w)
+    with jax.named_scope("lm_head"):
+        x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if cfg.family == "encoder":
+            w = params["head"]
+        else:
+            w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bsd,dv->bsv", x, w)
     # pin vocab-sharded logits: without this XLA may replicate (B,S,V) fp32
     # during the loss — tens of GB/device at 128k-150k vocabs.
     if mesh is not None and "model" in mesh.axis_names:

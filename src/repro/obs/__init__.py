@@ -1,12 +1,14 @@
 """Fleet flight recorder: structured event tracing, histogram metrics, and
 the controller decision audit.
 
-Three pieces, deliberately dependency-free (stdlib + numpy only) so every
-layer of the stack — engine, replica, dispatcher, runtime, client — can
-emit without import cycles:
+Three pieces, deliberately dependency-free (stdlib + numpy only; an
+enabled tracer imports ``jax.profiler`` when it is built, for its spans'
+annotations) so every layer of the stack — engine, replica, dispatcher,
+runtime, client — can emit without import cycles:
 
 * ``trace`` — ``Tracer``/``Span``: a ring-buffered structured event log on
-  the control-loop clock.  Request lifecycle, control-plane actions, and
+  the control-loop clock and the wall clock; spans also reach the
+  profiler's trace.  Request lifecycle, control-plane actions, and
   engine internals all land in one stream; exporters (JSONL, Chrome trace)
   read it back out.
 * ``metrics`` — ``MetricsRegistry``: counter / gauge / histogram families

@@ -38,7 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.serving.engine import PumpReport
+from repro.obs.trace import Tracer
+from repro.serving.engine import PumpReport, traced_pump
 
 
 @dataclass
@@ -166,6 +167,10 @@ class DiffusionSession:
         self._instant: List[int] = []
         self._slo: Dict[int, Tuple[int, int, float, int]] = {}
         self._seq = 0
+        # flight recorder, handed over by the owning replica (as on
+        # QueueSession)
+        self.tracer: Tracer = Tracer.disabled()
+        self.trace_tags: Dict[str, object] = {}
 
     # -- request intake -------------------------------------------------------
     def submit(self, rid: int, inp: np.ndarray, max_new: int, *,
@@ -258,58 +263,66 @@ class DiffusionSession:
         """One job cycle: admit into free slots, then ONE jitted dispatch
         advancing every active job ``steps_per_pump`` denoising steps.
         Jobs whose step budget hits zero complete, emitting their whole
-        digest in this report (non-streaming)."""
+        digest in this report (non-streaming).  Traced like the token
+        engine's pump: ``engine.pump`` around ``pump.admit``,
+        ``pump.decode`` (the denoising dispatch) and ``pump.decode_sync``."""
+        return traced_pump(self.tracer, self.trace_tags, self._pump_jobs)
+
+    def _phase(self, name: str):
+        return self.tracer.begin(name, cat="engine", sampled=True)
+
+    def _pump_jobs(self) -> PumpReport:
         eng, cfg = self.eng, self.eng.cfg
         report = PumpReport()
-        t0 = time.perf_counter()
-        for rid in self._instant:
-            report.completed[rid] = self.results[rid]
-        self._instant = []
-
-        for s in np.nonzero(self._rid < 0)[0]:
-            if not self.queue:
-                break
-            rid, inp, max_new = self._pop_next()
-            lat0, cond = eng.seed_job(inp)
-            self.lat, self.cond = eng._place(
-                self.lat, self.cond, lat0, cond, jnp.int32(int(s))
-            )
-            self._rid[s] = rid
-            self._rem[s] = cfg.denoise_steps
-            self._max_new[rid] = max_new
-            report.admitted.append(rid)
-        report.admit_s = time.perf_counter() - t0
+        with self._phase("pump.admit") as sp:
+            for rid in self._instant:
+                report.completed[rid] = self.results[rid]
+            self._instant = []
+            for s in np.nonzero(self._rid < 0)[0]:
+                if not self.queue:
+                    break
+                rid, inp, max_new = self._pop_next()
+                lat0, cond = eng.seed_job(inp)
+                self.lat, self.cond = eng._place(
+                    self.lat, self.cond, lat0, cond, jnp.int32(int(s))
+                )
+                self._rid[s] = rid
+                self._rem[s] = cfg.denoise_steps
+                self._max_new[rid] = max_new
+                report.admitted.append(rid)
+        report.admit_s = sp.wall_s
+        for rid in report.admitted:
+            self.tracer.event("req.admitted", cat="req", rid=rid,
+                              **self.trace_tags)
 
         active = self._rid >= 0
         report.occupancy = float(np.mean(active))
         if not np.any(active):
-            report.wall_s = time.perf_counter() - t0
             return report
 
-        t_disp = time.perf_counter()
-        self.lat, rem = eng._steps(
-            self.lat, self.cond, jnp.asarray(self._rem, jnp.int32),
-            cfg.steps_per_pump,
-        )
-        t_sync = time.perf_counter()
-        report.dispatch_s = t_sync - t_disp
-        self._rem = np.asarray(rem, np.int64)
-        done = np.nonzero(active & (self._rem == 0))[0]
-        if done.size:
-            lat_host = np.asarray(self.lat[jnp.asarray(done)])
-            for j, s in enumerate(done):
-                rid = int(self._rid[s])
-                toks = eng.digest(lat_host[j], self._max_new[rid])
-                self.results[rid] = toks
-                report.completed[rid] = toks
-                report.tokens[rid] = [int(v) for v in toks]
-                report.emitted[rid] = int(toks.size)
-                report.useful_tokens += int(toks.size)
-                self._rid[s] = -1
-                self._max_new.pop(rid, None)
-                self._slo.pop(rid, None)
-        report.sync_s = time.perf_counter() - t_sync
-        report.wall_s = time.perf_counter() - t0
+        with self._phase("pump.decode") as sp:
+            self.lat, rem = eng._steps(
+                self.lat, self.cond, jnp.asarray(self._rem, jnp.int32),
+                cfg.steps_per_pump,
+            )
+        report.dispatch_s = sp.wall_s
+        with self._phase("pump.decode_sync") as sp:
+            self._rem = np.asarray(rem, np.int64)
+            done = np.nonzero(active & (self._rem == 0))[0]
+            if done.size:
+                lat_host = np.asarray(self.lat[jnp.asarray(done)])
+                for j, s in enumerate(done):
+                    rid = int(self._rid[s])
+                    toks = eng.digest(lat_host[j], self._max_new[rid])
+                    self.results[rid] = toks
+                    report.completed[rid] = toks
+                    report.tokens[rid] = [int(v) for v in toks]
+                    report.emitted[rid] = int(toks.size)
+                    report.useful_tokens += int(toks.size)
+                    self._rid[s] = -1
+                    self._max_new.pop(rid, None)
+                    self._slo.pop(rid, None)
+        report.sync_s = sp.wall_s
         return report
 
 

@@ -83,6 +83,7 @@ import numpy as np
 from jax import lax
 
 from repro.models.model import Model
+from repro.obs.trace import Span, Tracer
 from repro.serving.backends import StateFrontier
 from repro.serving.paged_kv import TRASH_PAGE, BlockAllocator, KVFrontier
 from repro.serving.spec import (
@@ -684,7 +685,7 @@ class PumpReport:
     useful_tokens: int = 0
     wasted_tokens: int = 0
     occupancy: float = 0.0            # slot occupancy entering the chunk
-    wall_s: float = 0.0               # pump wall time (prefills + chunk + sync)
+    wall_s: float = 0.0               # pump wall time: the engine.pump span
     # paged-KV prefix cache activity this pump (zero when paging is off)
     prefix_hits: int = 0              # admissions served from cached pages
     prefix_misses: int = 0            # admissions that ran a full prefill
@@ -701,13 +702,31 @@ class PumpReport:
     drafted_tokens: int = 0           # draft tokens dispatched for verification
     accepted_tokens: int = 0          # drafts that survived verification
     spec_rounds: int = 0              # fused verify dispatches (>=1 draft in)
-    # per-pump phase walls (the observability breakdown of ``wall_s``):
-    # admission (queue pops + prefill setup/dispatch in legacy mode),
-    # dispatch (jitted mixed-step / chunk-scan launches), host sync
-    # (device->host token transfers + per-token host accounting)
+    # per-pump phase walls (the observability breakdown of ``wall_s``),
+    # read from the pump's phase spans: admission (``pump.admit``: queue
+    # pops + prefill setup/dispatch in legacy mode), dispatch
+    # (``pump.prefill`` less its ``pump.publish_sync`` reads, and
+    # ``pump.decode``: jitted mixed-step / chunk-scan launches), host sync
+    # (``pump.publish_sync``, ``pump.emit_sync``, ``pump.draft``,
+    # ``pump.decode_sync``: device->host transfers, drafting and
+    # per-token host accounting)
     admit_s: float = 0.0
     dispatch_s: float = 0.0
     sync_s: float = 0.0
+
+
+def traced_pump(tracer: Tracer, tags: Dict[str, Any],
+                run: Callable[[], PumpReport]) -> PumpReport:
+    """Run one pump cycle inside its ``engine.pump`` span: the span's wall
+    is the report's ``wall_s``, and its event carries the phase walls the
+    cycle's ``pump.*`` spans measured (plus ``tags``: replica, tier)."""
+    with tracer.begin("engine.pump", cat="engine", sampled=True, **tags) as sp:
+        report = run()
+        sp.end(admit_s=report.admit_s, dispatch_s=report.dispatch_s,
+               sync_s=report.sync_s, occupancy=report.occupancy,
+               completed=len(report.completed))
+    report.wall_s = sp.wall_s
+    return report
 
 
 class QueueSession:
@@ -799,6 +818,12 @@ class QueueSession:
         self._restored: List[Tuple[int, List[int]]] = []
         self._pending_recovered = 0
         self._pending_recomputed = 0
+        # -- flight recorder -------------------------------------------------
+        # the owning replica hands over the fleet's tracer and the tags its
+        # pump events carry; a bare session times its phases on a disabled
+        # tracer and records nothing
+        self.tracer: Tracer = Tracer.disabled()
+        self.trace_tags: Dict[str, Any] = {}
 
     # -- request intake -------------------------------------------------------
     def submit(self, rid: int, inp: np.ndarray, max_new: int, *,
@@ -1293,26 +1318,120 @@ class QueueSession:
         scan — prefill never preempts decode and admission adds zero
         per-request dispatches.  Legacy mode (``mixed_step=False``): the
         PR-3 loop — one B=1 prefill dispatch per admission, then the
-        chunk scan."""
-        if self.mixed:
-            return self._pump_mixed()
-        return self._pump_legacy()
+        chunk scan.
+
+        The cycle runs inside an ``engine.pump`` span and each phase in a
+        ``pump.*`` span on ``self.tracer``; the report's ``wall_s`` and
+        phase walls are those spans' walls."""
+        report = traced_pump(
+            self.tracer, self.trace_tags,
+            self._pump_mixed if self.mixed else self._pump_legacy)
+        if report.mixed_steps or report.chunk_steps:      # the model ran
+            self.eng.telemetry.decode_s += report.wall_s
+        return report
+
+    def _phase(self, name: str) -> Span:
+        return self.tracer.begin(name, cat="engine", sampled=True)
+
+    def _trace_admitted(self, report: PumpReport) -> None:
+        """``req.admitted`` for each request this pump gave a slot, stamped
+        as the pump's admission phase ends."""
+        for rid in report.admitted:
+            self.tracer.event("req.admitted", cat="req", rid=rid,
+                              **self.trace_tags)
 
     def _pump_legacy(self) -> PumpReport:
         """One admission pass + one chunk scan (per-request prefill)."""
         eng, slots = self.eng, self.slots
         chunk = max(1, eng.cfg.decode_chunk)
         report = PumpReport()
-        t0 = time.perf_counter()
-        for rid in self._instant:
-            report.completed[rid] = self.results[rid]
-        self._instant = []
-
-        # admit while there is work and a free slot
         if self.paged:
             st = self.allocator.stats
             stats0 = (st.full_hits + st.prefix_hits, st.misses,
                       st.reused_tokens, st.prefilled_tokens)
+        with self._phase("pump.admit") as sp:
+            self._admit_legacy(report)
+        report.admit_s = sp.wall_s
+        self._trace_admitted(report)
+
+        report.occupancy = slots.occupancy
+        if self.paged:
+            st = self.allocator.stats
+            report.prefix_hits = st.full_hits + st.prefix_hits - stats0[0]
+            report.prefix_misses = st.misses - stats0[1]
+            report.reused_tokens = st.reused_tokens - stats0[2]
+            report.prefilled_tokens = st.prefilled_tokens - stats0[3]
+            report.page_occupancy = self.allocator.occupancy
+            report.cached_pages = self.allocator.cached_pages
+        if report.occupancy == 0.0:                   # nothing to decode
+            self._drain_recovery(report)
+            return report
+
+        # decode one chunk for the whole slot batch
+        with self._phase("pump.decode") as sp:
+            active = jnp.asarray(slots.request_id >= 0)
+            if self.paged:
+                self.cache, self.tok, self.lens, self.key, toks = eng._chunk_paged(
+                    eng.params, self.cache, jnp.asarray(self.tables),
+                    self.tok, self.lens, active, self.key, chunk
+                )
+            else:
+                self.cache, self.tok, self.lens, self.key, toks = eng._chunk(
+                    eng.params, self.cache, self.tok, self.lens, active,
+                    self.key, chunk
+                )
+        report.dispatch_s = sp.wall_s
+        with self._phase("pump.decode_sync") as sp:
+            toks_np = np.asarray(toks)                # ONE transfer per chunk
+            n_slots = slots.n_slots
+            for t in range(chunk):
+                active = np.nonzero(slots.request_id >= 0)[0]
+                for s in active:
+                    rid = int(slots.request_id[s])
+                    val = int(toks_np[t, s])
+                    self._out[rid].append(val)
+                    report.emitted[rid] = report.emitted.get(rid, 0) + 1
+                    report.tokens.setdefault(rid, []).append(val)
+                report.useful_tokens += len(active)
+                report.wasted_tokens += n_slots - len(active)
+                for rid in slots.step():
+                    tokens = np.asarray(self._out.pop(rid), np.int64)
+                    self.results[rid] = tokens
+                    report.completed[rid] = tokens
+                    self._retire(rid)
+                    if self.paged:
+                        self._release_rid(rid)
+            if self.paged:
+                # re-sample AFTER completions released their pages, so a
+                # draining session reports decaying occupancy, not the
+                # admission-time peak
+                report.page_occupancy = self.allocator.occupancy
+                report.cached_pages = self.allocator.cached_pages
+        report.sync_s = sp.wall_s
+        report.chunk_steps = chunk
+        self._drain_recovery(report)
+
+        tel = eng.telemetry
+        tel.chunks += 1
+        tel.useful_tokens += report.useful_tokens
+        tel.wasted_tokens += report.wasted_tokens
+        tel.completed_requests += len(report.completed)
+        tel.prefix_hits += report.prefix_hits
+        tel.prefix_misses += report.prefix_misses
+        tel.reused_tokens += report.reused_tokens
+        tel.prefilled_tokens += report.prefilled_tokens
+        tel.recovered_tokens += report.recovered_tokens
+        tel.recomputed_prefill_tokens += report.recomputed_prefill_tokens
+        return report
+
+    def _admit_legacy(self, report: PumpReport) -> None:
+        """The legacy admission pass: while there is work and a free slot,
+        place each request (restored frontier, paged admission, or one B=1
+        prefill dispatch)."""
+        eng, slots = self.eng, self.slots
+        for rid in self._instant:
+            report.completed[rid] = self.results[rid]
+        self._instant = []
         for s in slots.free:
             if not self.queue:
                 break
@@ -1354,80 +1473,6 @@ class QueueSession:
             slots.admit(int(s), rid, max_new)
             report.admitted.append(rid)
         self._emit_restored(report)
-        report.admit_s = time.perf_counter() - t0
-
-        report.occupancy = slots.occupancy
-        if self.paged:
-            st = self.allocator.stats
-            report.prefix_hits = st.full_hits + st.prefix_hits - stats0[0]
-            report.prefix_misses = st.misses - stats0[1]
-            report.reused_tokens = st.reused_tokens - stats0[2]
-            report.prefilled_tokens = st.prefilled_tokens - stats0[3]
-            report.page_occupancy = self.allocator.occupancy
-            report.cached_pages = self.allocator.cached_pages
-        if report.occupancy == 0.0:                   # nothing to decode
-            self._drain_recovery(report)
-            report.wall_s = time.perf_counter() - t0
-            return report
-
-        # decode one chunk for the whole slot batch
-        t_disp = time.perf_counter()
-        active = jnp.asarray(slots.request_id >= 0)
-        if self.paged:
-            self.cache, self.tok, self.lens, self.key, toks = eng._chunk_paged(
-                eng.params, self.cache, jnp.asarray(self.tables),
-                self.tok, self.lens, active, self.key, chunk
-            )
-        else:
-            self.cache, self.tok, self.lens, self.key, toks = eng._chunk(
-                eng.params, self.cache, self.tok, self.lens, active,
-                self.key, chunk
-            )
-        t_sync = time.perf_counter()
-        report.dispatch_s = t_sync - t_disp
-        toks_np = np.asarray(toks)                    # ONE transfer per chunk
-        n_slots = slots.n_slots
-        for t in range(chunk):
-            active = np.nonzero(slots.request_id >= 0)[0]
-            for s in active:
-                rid = int(slots.request_id[s])
-                val = int(toks_np[t, s])
-                self._out[rid].append(val)
-                report.emitted[rid] = report.emitted.get(rid, 0) + 1
-                report.tokens.setdefault(rid, []).append(val)
-            report.useful_tokens += len(active)
-            report.wasted_tokens += n_slots - len(active)
-            for rid in slots.step():
-                tokens = np.asarray(self._out.pop(rid), np.int64)
-                self.results[rid] = tokens
-                report.completed[rid] = tokens
-                self._retire(rid)
-                if self.paged:
-                    self._release_rid(rid)
-        if self.paged:
-            # re-sample AFTER completions released their pages, so a
-            # draining session reports decaying occupancy, not the
-            # admission-time peak
-            report.page_occupancy = self.allocator.occupancy
-            report.cached_pages = self.allocator.cached_pages
-        report.sync_s = time.perf_counter() - t_sync
-        report.chunk_steps = chunk
-        self._drain_recovery(report)
-        report.wall_s = time.perf_counter() - t0
-
-        tel = eng.telemetry
-        tel.chunks += 1
-        tel.decode_s += report.wall_s
-        tel.useful_tokens += report.useful_tokens
-        tel.wasted_tokens += report.wasted_tokens
-        tel.completed_requests += len(report.completed)
-        tel.prefix_hits += report.prefix_hits
-        tel.prefix_misses += report.prefix_misses
-        tel.reused_tokens += report.reused_tokens
-        tel.prefilled_tokens += report.prefilled_tokens
-        tel.recovered_tokens += report.recovered_tokens
-        tel.recomputed_prefill_tokens += report.recomputed_prefill_tokens
-        return report
 
     # -- mixed-batch chunked prefill ------------------------------------------
     def _akey(self) -> Optional[jax.Array]:
@@ -1592,45 +1637,16 @@ class QueueSession:
         eng, slots = self.eng, self.slots
         chunk = max(1, eng.cfg.decode_chunk)
         n_slots = slots.n_slots
-        greedy = eng.cfg.temperature <= 0.0
         report = PumpReport()
-        t0 = time.perf_counter()
-        for rid in self._instant:
-            report.completed[rid] = self.results[rid]
-        self._instant = []
 
         if self.paged:
             st0 = self.allocator.stats
             stats0 = (st0.full_hits + st0.prefix_hits, st0.misses,
                       st0.reused_tokens, st0.prefilled_tokens)
-
-        # admit while there is work and a slot neither decoding nor ingesting
-        for s in slots.free:
-            if not self.queue:
-                break
-            s = int(s)
-            if s in self._prefilling:
-                continue
-            rid, inp, max_new = self._pop_next()
-            fr = self._frontiers.pop(rid, None)
-            if fr is not None:
-                if not self._admit_restored(s, rid, fr, max_new):
-                    # page pressure: requeue with the frontier intact so the
-                    # retry still resumes instead of re-prefilling
-                    self._frontiers[rid] = fr
-                    self.queue.insert(0, (rid, inp, max_new))
-                    break
-            elif self.paged:
-                if not self._admit_paged_mixed(s, rid, inp, max_new):
-                    # page pressure: put it back and retry after decodes
-                    # release pages (completions free at chunk boundaries)
-                    self.queue.insert(0, (rid, inp, max_new))
-                    break
-            else:
-                self._admit_mixed(s, rid, inp, max_new)
-            report.admitted.append(rid)
-        self._emit_restored(report)
-        report.admit_s = time.perf_counter() - t0
+        with self._phase("pump.admit") as sp:
+            self._admit_wave(report)
+        report.admit_s = sp.wall_s
+        self._trace_admitted(report)
 
         decode_active = slots.request_id >= 0
         report.occupancy = (
@@ -1662,20 +1678,147 @@ class QueueSession:
         if not sched and not decode_active.any():       # nothing to run
             _paged_report_tail()
             self._drain_recovery(report)
-            report.wall_s = time.perf_counter() - t0
             return report
 
-        # ---- the fused prefill+decode dispatches --------------------------
-        # drive this pump's admissions to completion: every iteration is one
-        # budget-bounded mixed step, and decode rows advance a token in each
-        # — ingestion wall is decode wall, never a stall (the legacy pump
-        # symmetrically runs ALL its B=1 admission prefills per cycle, with
-        # every decode slot idle while it does).  Emitted-token reads are
-        # deferred past the loop: the carried-token arrays stay valid (tok
-        # is never donated), so the steps pipeline with no per-step sync.
+        if sched:
+            # the fused prefill+decode dispatches; the paged tier's logits
+            # reads inside them are host syncs, not dispatch
+            with self._phase("pump.prefill") as sp:
+                emits, done, publish_s = self._ingest(report, sched)
+                sp.end(steps=report.mixed_steps)
+            report.dispatch_s += sp.wall_s - publish_s
+            report.sync_s += publish_s
+            # flush the deferred emitted-token reads (one D2H per step, all
+            # issued after the dispatches), then the completions they finish
+            with self._phase("pump.emit_sync") as sp:
+                for tok_dev, pairs in emits:
+                    vals = np.asarray(tok_dev)
+                    for s, rid in pairs:
+                        val = int(vals[s])
+                        self._out[rid].append(val)
+                        report.emitted[rid] = report.emitted.get(rid, 0) + 1
+                        report.tokens.setdefault(rid, []).append(val)
+                for rid in done:
+                    _complete(rid)
+            report.sync_s += sp.wall_s
+
+        # ---- the decode phase ---------------------------------------------
+        decode_active = slots.request_id >= 0
+        if decode_active.any() and self.spec_k > 0:
+            # speculative rounds replace the chunk scan: each round is one
+            # fused draft-verify dispatch advancing every decoding slot by
+            # 1 + accepted tokens (>= the scan's 1 token per step)
+            self._decode_speculative(report, chunk, _complete)
+        elif decode_active.any():
+            with self._phase("pump.decode") as sp:
+                active_j = jnp.asarray(decode_active)
+                lens_dev = jnp.asarray(self._lens_host, jnp.int32)
+                if self.paged:
+                    self.cache, self.tok, self.lens, self.key, toks = eng._chunk_paged(
+                        eng.params, self.cache, jnp.asarray(self.tables),
+                        self.tok, lens_dev, active_j, self.key, chunk
+                    )
+                else:
+                    self.cache, self.tok, self.lens, self.key, toks = eng._chunk(
+                        eng.params, self.cache, self.tok, lens_dev, active_j,
+                        self.key, chunk
+                    )
+                self._lens_host[decode_active] = np.minimum(
+                    self._lens_host[decode_active] + chunk, eng.cfg.max_len - 1
+                )
+            report.dispatch_s += sp.wall_s
+            with self._phase("pump.decode_sync") as sp:
+                toks_np = np.asarray(toks)            # ONE transfer per chunk
+                for t in range(chunk):
+                    active = np.nonzero(slots.request_id >= 0)[0]
+                    for s in active:
+                        rid = int(slots.request_id[s])
+                        val = int(toks_np[t, s])
+                        self._out[rid].append(val)
+                        report.emitted[rid] = report.emitted.get(rid, 0) + 1
+                        report.tokens.setdefault(rid, []).append(val)
+                    report.useful_tokens += len(active)
+                    report.wasted_tokens += n_slots - len(active)
+                    for rid in slots.step():
+                        _complete(rid)
+            report.chunk_steps = chunk
+            report.sync_s += sp.wall_s
+
+        _paged_report_tail()
+        self._drain_recovery(report)
+
+        tel = eng.telemetry
+        tel.mixed_steps += report.mixed_steps
+        tel.prefill_chunks += report.prefill_chunks
+        if report.chunk_steps:
+            tel.chunks += 1
+        tel.useful_tokens += report.useful_tokens
+        tel.wasted_tokens += report.wasted_tokens
+        tel.completed_requests += len(report.completed)
+        tel.prefix_hits += report.prefix_hits
+        tel.prefix_misses += report.prefix_misses
+        tel.reused_tokens += report.reused_tokens
+        tel.prefilled_tokens += report.prefilled_tokens
+        tel.recovered_tokens += report.recovered_tokens
+        tel.recomputed_prefill_tokens += report.recomputed_prefill_tokens
+        tel.drafted_tokens += report.drafted_tokens
+        tel.accepted_tokens += report.accepted_tokens
+        tel.spec_rounds += report.spec_rounds
+        return report
+
+    def _admit_wave(self, report: PumpReport) -> None:
+        """The mixed admission pass: while there is work and a slot neither
+        decoding nor ingesting, queue the request's prompt for chunked
+        prefill (or inject its restored frontier)."""
+        slots = self.slots
+        for rid in self._instant:
+            report.completed[rid] = self.results[rid]
+        self._instant = []
+        for s in slots.free:
+            if not self.queue:
+                break
+            s = int(s)
+            if s in self._prefilling:
+                continue
+            rid, inp, max_new = self._pop_next()
+            fr = self._frontiers.pop(rid, None)
+            if fr is not None:
+                if not self._admit_restored(s, rid, fr, max_new):
+                    # page pressure: requeue with the frontier intact so the
+                    # retry still resumes instead of re-prefilling
+                    self._frontiers[rid] = fr
+                    self.queue.insert(0, (rid, inp, max_new))
+                    break
+            elif self.paged:
+                if not self._admit_paged_mixed(s, rid, inp, max_new):
+                    # page pressure: put it back and retry after decodes
+                    # release pages (completions free at chunk boundaries)
+                    self.queue.insert(0, (rid, inp, max_new))
+                    break
+            else:
+                self._admit_mixed(s, rid, inp, max_new)
+            report.admitted.append(rid)
+        self._emit_restored(report)
+
+    def _ingest(self, report: PumpReport, sched: List[Tuple[int, np.ndarray]]
+                ) -> Tuple[List[Tuple[Any, List[Tuple[int, int]]]], List[int],
+                           float]:
+        """Drive this pump's admissions to completion: every iteration is
+        one budget-bounded mixed step, and decode rows advance a token in
+        each — ingestion wall is decode wall, never a stall (the legacy
+        pump symmetrically runs ALL its B=1 admission prefills per cycle,
+        with every decode slot idle while it does).  Emitted-token reads
+        are deferred past the loop: the carried-token arrays stay valid
+        (tok is never donated), so the steps pipeline with no per-step
+        sync.  Returns the deferred (token array, (slot, rid) pairs) reads,
+        the rids the steps finished, and the seconds spent in the paged
+        tier's ``pump.publish_sync`` logits reads."""
+        eng, slots = self.eng, self.slots
+        n_slots = slots.n_slots
+        greedy = eng.cfg.temperature <= 0.0
         deferred_emits: List[Tuple[Any, List[Tuple[int, int]]]] = []
         deferred_done: List[int] = []
-        t_disp = time.perf_counter()
+        publish_s = 0.0
         while sched:
             decode_active = slots.request_id >= 0
             Q = eng.chunk_quantum(self.token_budget)    # the one chunk width
@@ -1738,8 +1881,13 @@ class QueueSession:
                     tok0 = eng._sample(logits[s][None],
                                        self._prefilling[s]["akey"])[0]
                     self.tok = self.tok.at[s].set(tok0)
-            logits_np = (np.asarray(logits)
-                         if self.paged and completing else None)
+            logits_np = None
+            if self.paged and completing:
+                # the prefix cache publishes the finished prompts' logits:
+                # this read waits for the step to finish on the device
+                with self._phase("pump.publish_sync") as sp:
+                    logits_np = np.asarray(logits)
+                publish_s += sp.wall_s
             report.useful_tokens += len(pairs)
             report.wasted_tokens += n_slots - len(pairs) - len(sched)
             deferred_done.extend(slots.step())
@@ -1764,87 +1912,7 @@ class QueueSession:
                     del self._prefilling[s]
                     eng.telemetry.prefills += 1
             sched = self._schedule_chunks()
-
-        # flush the deferred emitted-token reads (one D2H per step, all
-        # issued after the dispatches), then the completions they finish
-        t_sync = time.perf_counter()
-        report.dispatch_s += t_sync - t_disp
-        for tok_dev, pairs in deferred_emits:
-            vals = np.asarray(tok_dev)
-            for s, rid in pairs:
-                val = int(vals[s])
-                self._out[rid].append(val)
-                report.emitted[rid] = report.emitted.get(rid, 0) + 1
-                report.tokens.setdefault(rid, []).append(val)
-        for rid in deferred_done:
-            _complete(rid)
-        report.sync_s += time.perf_counter() - t_sync
-
-        # ---- the decode phase ---------------------------------------------
-        decode_active = slots.request_id >= 0
-        if decode_active.any() and self.spec_k > 0:
-            # speculative rounds replace the chunk scan: each round is one
-            # fused draft-verify dispatch advancing every decoding slot by
-            # 1 + accepted tokens (>= the scan's 1 token per step)
-            self._decode_speculative(report, chunk, _complete)
-        elif decode_active.any():
-            t_disp = time.perf_counter()
-            active_j = jnp.asarray(decode_active)
-            lens_dev = jnp.asarray(self._lens_host, jnp.int32)
-            if self.paged:
-                self.cache, self.tok, self.lens, self.key, toks = eng._chunk_paged(
-                    eng.params, self.cache, jnp.asarray(self.tables),
-                    self.tok, lens_dev, active_j, self.key, chunk
-                )
-            else:
-                self.cache, self.tok, self.lens, self.key, toks = eng._chunk(
-                    eng.params, self.cache, self.tok, lens_dev, active_j,
-                    self.key, chunk
-                )
-            self._lens_host[decode_active] = np.minimum(
-                self._lens_host[decode_active] + chunk, eng.cfg.max_len - 1
-            )
-            t_sync = time.perf_counter()
-            report.dispatch_s += t_sync - t_disp
-            toks_np = np.asarray(toks)                # ONE transfer per chunk
-            for t in range(chunk):
-                active = np.nonzero(slots.request_id >= 0)[0]
-                for s in active:
-                    rid = int(slots.request_id[s])
-                    val = int(toks_np[t, s])
-                    self._out[rid].append(val)
-                    report.emitted[rid] = report.emitted.get(rid, 0) + 1
-                    report.tokens.setdefault(rid, []).append(val)
-                report.useful_tokens += len(active)
-                report.wasted_tokens += n_slots - len(active)
-                for rid in slots.step():
-                    _complete(rid)
-            report.chunk_steps = chunk
-            report.sync_s += time.perf_counter() - t_sync
-
-        _paged_report_tail()
-        self._drain_recovery(report)
-        report.wall_s = time.perf_counter() - t0
-
-        tel = eng.telemetry
-        tel.mixed_steps += report.mixed_steps
-        tel.prefill_chunks += report.prefill_chunks
-        if report.chunk_steps:
-            tel.chunks += 1
-        tel.decode_s += report.wall_s
-        tel.useful_tokens += report.useful_tokens
-        tel.wasted_tokens += report.wasted_tokens
-        tel.completed_requests += len(report.completed)
-        tel.prefix_hits += report.prefix_hits
-        tel.prefix_misses += report.prefix_misses
-        tel.reused_tokens += report.reused_tokens
-        tel.prefilled_tokens += report.prefilled_tokens
-        tel.recovered_tokens += report.recovered_tokens
-        tel.recomputed_prefill_tokens += report.recomputed_prefill_tokens
-        tel.drafted_tokens += report.drafted_tokens
-        tel.accepted_tokens += report.accepted_tokens
-        tel.spec_rounds += report.spec_rounds
-        return report
+        return deferred_emits, deferred_done, publish_s
 
     # -- speculative decode rounds -------------------------------------------
     def _decode_speculative(self, report: PumpReport, rounds: int,
@@ -1878,13 +1946,15 @@ class QueueSession:
         Qs = spec_quantum(self.spec_k)
         drafter = eng.drafter
         # ONE initial carry sync; afterwards the verdicts keep it host-known
-        carry = np.asarray(self.tok).astype(np.int64).copy()
+        with self._phase("pump.decode_sync") as sp:
+            carry = np.asarray(self.tok).astype(np.int64).copy()
+        report.sync_s += sp.wall_s
         executed = 0
         for _ in range(rounds):
             active = np.nonzero(slots.request_id >= 0)[0]
             if len(active) == 0:
                 break
-            t_draft = time.perf_counter()
+            draft = self._phase("pump.draft")
             chunks_np = np.zeros((n_slots, Qs), np.int32)
             new_lens = np.zeros((n_slots,), np.int32)
             d_of = np.zeros((n_slots,), np.int64)
@@ -1914,19 +1984,20 @@ class QueueSession:
             is_decode = jnp.asarray(slots.request_id >= 0)
             lens_dev = jnp.asarray(self._lens_host, jnp.int32)
             tok_dev = jnp.asarray(carry.astype(np.int32))
-            t_disp = time.perf_counter()
-            if self.paged:
-                verdict, self.cache, self.key = eng._spec_paged(
-                    eng.params, self.cache, jnp.asarray(self.tables),
-                    jnp.asarray(chunks_np), tok_dev, lens_dev,
-                    jnp.asarray(new_lens), is_decode, self.key, aw,
-                )
-            else:
-                verdict, self.cache, self.key = eng._spec(
-                    eng.params, self.cache, jnp.asarray(chunks_np), tok_dev,
-                    lens_dev, jnp.asarray(new_lens), is_decode, self.key, aw,
-                )
-            t_sync = time.perf_counter()
+            draft.end()
+            with self._phase("pump.decode") as disp:
+                if self.paged:
+                    verdict, self.cache, self.key = eng._spec_paged(
+                        eng.params, self.cache, jnp.asarray(self.tables),
+                        jnp.asarray(chunks_np), tok_dev, lens_dev,
+                        jnp.asarray(new_lens), is_decode, self.key, aw,
+                    )
+                else:
+                    verdict, self.cache, self.key = eng._spec(
+                        eng.params, self.cache, jnp.asarray(chunks_np), tok_dev,
+                        lens_dev, jnp.asarray(new_lens), is_decode, self.key, aw,
+                    )
+            sync = self._phase("pump.decode_sync")
             v = np.asarray(verdict)           # ONE (3, B, Q) transfer/round
             counts = np.zeros(n_slots, np.int64)
             round_drafted = 0
@@ -1963,11 +2034,11 @@ class QueueSession:
                     rate if self.spec_accept_ewma is None
                     else 0.3 * rate + 0.7 * self.spec_accept_ewma)
             executed += 1
-            t_done = time.perf_counter()
-            report.dispatch_s += t_sync - t_disp
-            report.sync_s += (t_disp - t_draft) + (t_done - t_sync)
             for rid in slots.advance(counts):
                 complete(rid)
+            sync.end()
+            report.dispatch_s += disp.wall_s
+            report.sync_s += draft.wall_s + sync.wall_s
         # re-sync the device-side mirrors once for whoever reads them next
         # (legacy-path admissions, introspection); _lens_host stayed exact
         self.tok = jnp.asarray(carry.astype(np.int32))

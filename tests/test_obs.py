@@ -12,6 +12,7 @@ timeline covering >= 99% of completed requests.
 import json
 import os
 import sys
+import time
 from types import SimpleNamespace
 
 import jax
@@ -34,9 +35,12 @@ from repro.obs import (
 )
 from repro.obs.trace import load_jsonl
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "tools"))
+sys.path.insert(0, ROOT)
 import fleet_top  # noqa: E402
 import trace_export  # noqa: E402
+from benchmarks.chip import trace_reduce  # noqa: E402
 
 
 # ---------------------------------------------------------------------------
@@ -83,6 +87,110 @@ def test_tracer_clock_and_span_duration():
     (ev,) = tr.to_list()
     assert ev["t"] == 5.0 and ev["dur"] == 2.5 and ev["replica"] == "r1"
     assert tr.event("later") and tr.to_list()[-1]["t"] == 7.5
+
+
+def test_span_carries_wall_clock_and_parent():
+    now = {"t": 1.0}
+    tr = Tracer(clock=lambda: now["t"])
+    w_before = time.perf_counter()
+    with tr.begin("outer", replica="r1"):
+        tr.event("inside")
+        with tr.begin("inner", cat="engine"):
+            time.sleep(0.002)
+        now["t"] = 2.0
+    w_after = time.perf_counter()
+    ev = {e["name"]: e for e in tr.to_list()}
+    outer, inner, inside = ev["outer"], ev["inner"], ev["inside"]
+    # every event carries the wall clock beside the owner's clock
+    assert w_before <= outer["w"] <= inside["w"] <= inner["w"]
+    # a span: t/dur on the owner's clock, w/wall_s on the wall clock
+    assert (outer["t"], outer["dur"]) == (1.0, 1.0)
+    assert (inner["t"], inner["dur"]) == (1.0, 0.0)
+    assert inner["wall_s"] >= 0.002
+    assert inner["w"] + inner["wall_s"] <= outer["w"] + outer["wall_s"] <= w_after
+    # the span open when one began is its parent
+    assert outer["parent"] is None and inner["parent"] == "outer"
+    assert outer["replica"] == "r1"
+    assert "parent" not in inside
+
+
+def test_sibling_spans_share_a_parent_and_the_stack_unwinds():
+    tr = Tracer()
+    with tr.begin("fleet.tick"):
+        for name in ("fleet.intake", "fleet.control", "fleet.dispatch"):
+            with tr.begin(name):
+                pass
+        with tr.begin("engine.pump", cat="engine"):
+            with tr.begin("pump.admit", cat="engine"):
+                pass
+    with tr.begin("fleet.tick"):
+        pass
+    parents = [(e["name"], e["parent"]) for e in tr.to_list()]
+    assert parents == [
+        ("fleet.intake", "fleet.tick"), ("fleet.control", "fleet.tick"),
+        ("fleet.dispatch", "fleet.tick"), ("pump.admit", "engine.pump"),
+        ("engine.pump", "fleet.tick"), ("fleet.tick", None),
+        ("fleet.tick", None)]
+
+
+def test_sampled_span_keeps_or_drops_its_children_whole():
+    tr = Tracer(sample=0.5)
+    walls = []
+    for i in range(4):
+        with tr.begin("engine.pump", t=float(i), cat="engine",
+                      sampled=True) as sp:
+            for name in ("pump.admit", "pump.decode"):
+                with tr.begin(name, cat="engine", sampled=True):
+                    pass
+        walls.append(sp.wall_s)
+    assert len(tr.select(name="engine.pump")) == 2
+    assert len(tr.select(name="pump.admit")) == 2
+    assert len(tr.select(name="pump.decode")) == 2
+    assert tr.sampled_out == 2                # one stride per pump tree
+    assert all(w > 0 for w in walls)          # timed even when dropped
+
+
+def test_span_unwound_by_an_exception_is_dropped():
+    tr = Tracer()
+    with pytest.raises(RuntimeError):
+        with tr.begin("engine.pump", cat="engine"):
+            tr.begin("pump.prefill", cat="engine")     # never ended
+            raise RuntimeError("pump failed")
+    with tr.begin("fleet.tick"):
+        pass
+    (ev,) = tr.to_list()
+    assert ev["name"] == "fleet.tick" and ev["parent"] is None
+
+
+def test_disabled_tracer_spans_only_time_themselves():
+    tr = Tracer.disabled()
+    with tr.begin("outer") as outer:
+        with tr.begin("inner", sampled=True) as inner:
+            time.sleep(0.001)
+    assert inner.wall_s >= 0.001 and outer.wall_s >= inner.wall_s
+    assert inner.parent is None and not inner.recorded
+    assert len(tr.events) == 0 and tr.emitted == 0
+
+
+def test_spans_reach_the_profiler_trace_nested(tmp_path):
+    """A recorded span holds a profiler annotation of its name: the xplane
+    shows the pair on the host plane with its nesting intact."""
+    tr = Tracer()
+    jax.block_until_ready(jax.numpy.ones(4) * 2)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.begin("fleet.tick"):
+            with tr.begin("engine.pump", cat="engine"):
+                jax.block_until_ready(jax.numpy.ones(4) * 2)
+    finally:
+        jax.profiler.stop_trace()
+    got = trace_reduce.load(trace_reduce.find_xplane(str(tmp_path)),
+                            ("fleet.tick", "engine.pump")).spans
+    (tick,) = [sp for sp in got if sp[0] == "fleet.tick"]
+    (pump,) = [sp for sp in got if sp[0] == "engine.pump"]
+    assert tick[1] <= pump[1] < pump[2] <= tick[2]
+    assert {e["name"]: e["parent"] for e in tr.to_list()} == {
+        "engine.pump": "fleet.tick", "fleet.tick": None}
 
 
 def test_tracer_jsonl_roundtrip_with_numpy(tmp_path):
@@ -478,6 +586,105 @@ def spot_engine():
         max_len=MAX_LEN, decode_batch=3, temperature=0.0, decode_chunk=4,
         mixed_step=True, prefill_chunk=64, paged_kv=True, page_size=PAGE,
         num_pages=NUM_PAGES, prefix_reuse=True))
+
+
+def _within(child, parent):
+    return (parent["w"] <= child["w"]
+            and child["w"] + child["wall_s"] <= parent["w"] + parent["wall_s"])
+
+
+def test_fleet_spans_cover_ticks_pumps_and_first_tokens(spot_engine):
+    """A paged fleet's record: every tick's phases under ``fleet.tick``,
+    every pump's phases under ``engine.pump``, the pump's phase walls read
+    from those spans (the prefix cache's logits reads as sync), and each
+    request stamped queued -> dispatched -> admitted -> first token on the
+    wall clock."""
+    from repro.fleet.client import FleetClient
+    from repro.fleet.runtime import FleetConfig, FleetRuntime, TierSpec
+    from repro.serving.api import InferenceRequest
+
+    tier = TierSpec(name="spot", arch="qwen3-0.6b", max_len=MAX_LEN,
+                    decode_batch=3, decode_chunk=4, queue_limit=6,
+                    base_capacity=1, initial_replicas=1, paged_kv=True,
+                    page_size=PAGE, num_pages=NUM_PAGES, prefill_chunk=64)
+    rt = FleetRuntime([tier], [], FleetConfig(seed=0, warmup=False))
+    rt._engines["spot"] = spot_engine
+    client = FleetClient(rt)
+    rng = np.random.default_rng(3)
+    vocab = spot_engine.model.cfg.vocab_size
+    rids = [client.submit(InferenceRequest(
+        prompt=rng.integers(0, vocab, n), max_new=6)).rid
+        for n in (40, 70, PLEN, 24)]
+    client.drain()
+    events = rt.tracer.to_list()
+    by = lambda name: [e for e in events if e["name"] == name]  # noqa: E731
+
+    ticks = by("fleet.tick")
+    assert len(ticks) == rt.ticks and all(e["parent"] is None for e in ticks)
+    for name in ("fleet.intake", "fleet.control", "fleet.dispatch",
+                 "fleet.autoscale"):
+        phases = by(name)
+        assert len(phases) == len(ticks)
+        assert all(e["parent"] == "fleet.tick" for e in phases)
+        assert all(any(_within(p, tk) for tk in ticks) for p in phases)
+
+    pumps = by("engine.pump")
+    assert pumps and all(e["parent"] == "fleet.tick" for e in pumps)
+    assert {e["parent"] for e in by("fleet.deliver")} == {"fleet.tick"}
+    assert by("pump.publish_sync"), "the paged tier published no prompt"
+    for pump in pumps:
+        assert pump["replica"] == "spot/r1" and pump["tier"] == "spot"
+        kids = [e for e in events if e["name"].startswith("pump.")
+                and _within(e, pump)]
+        wall = {}
+        for k in kids:
+            wall[k["name"]] = wall.get(k["name"], 0.0) + k["wall_s"]
+        assert pump["admit_s"] == pytest.approx(wall["pump.admit"])
+        publish = wall.get("pump.publish_sync", 0.0)
+        assert pump["sync_s"] == pytest.approx(
+            publish + wall.get("pump.emit_sync", 0.0)
+            + wall.get("pump.decode_sync", 0.0))
+        assert pump["dispatch_s"] == pytest.approx(
+            wall.get("pump.prefill", 0.0) - publish
+            + wall.get("pump.decode", 0.0))
+        phases = pump["admit_s"] + pump["dispatch_s"] + pump["sync_s"]
+        assert 0.5 * pump["wall_s"] <= phases <= pump["wall_s"]
+        for k in kids:
+            want = "pump.prefill" if k["name"] == "pump.publish_sync" \
+                else "engine.pump"
+            assert k["parent"] == want
+    for pre in by("pump.prefill"):
+        assert pre["steps"] >= 1
+
+    chains = request_chains(events)
+    for rid in rids:
+        chain = chains[rid]
+        assert validate_chain(chain) == []
+        w = {e["name"]: e["w"] for e in reversed(chain)}   # first of each
+        assert (w["req.queued"] <= w["req.dispatched"] <= w["req.admitted"]
+                <= w["req.first_token"] <= w["req.completed"])
+        (adm,) = [e for e in chain if e["name"] == "req.admitted"]
+        assert adm["replica"] == "spot/r1"
+
+
+def test_step_ops_carry_the_model_scopes(spot_engine):
+    """The mixed step's ops name the model's scopes in their metadata,
+    where a device trace's op records find them."""
+    import re
+
+    sess = spot_engine.new_session()
+    b = spot_engine.cfg.decode_batch
+    q = spot_engine.chunk_quantum(64)
+    text = spot_engine._mixed_paged.lower(
+        spot_engine.params, sess.cache, jax.numpy.asarray(sess.tables),
+        jax.numpy.zeros((b, q), jax.numpy.int32), sess.tok,
+        jax.numpy.zeros((b,), jax.numpy.int32),
+        jax.numpy.ones((b,), jax.numpy.int32),
+        jax.numpy.zeros((b,), bool), 64).compile().as_text()
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    scopes = {p for path in paths for p in path.split("/")}
+    assert {"attn", "kv_write", "mlp", "fuse_weights", "lm_head"} <= scopes
+    assert any("attn/kv_write/" in path for path in paths)
 
 
 @pytest.mark.slow

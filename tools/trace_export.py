@@ -70,7 +70,7 @@ def _us(t: float) -> float:
 
 
 def _args_of(ev: Dict[str, Any]) -> Dict[str, Any]:
-    return {k: v for k, v in ev.items() if k not in ("t", "name", "cat")}
+    return {k: v for k, v in ev.items() if k not in ("t", "w", "name", "cat")}
 
 
 def _serve_slices(chain: List[Dict[str, Any]]
