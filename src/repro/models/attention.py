@@ -28,9 +28,32 @@ from repro.models import layers
 
 
 class KVCache(NamedTuple):
-    k: jax.Array          # (B, S_cache, Hkv, Dh)
+    """Keys and values.  ``attention_block`` returns one layer's
+    (B, S_cache, Hkv, Dh).  The decode, mixed and paged-prefill steps take
+    the stacked cache of every layer, (L, B, S_cache, Hkv, Dh) or the page
+    pool (L, P, ps, Hkv, Dh), with a layer index: they write that layer's
+    new rows into it in place, attend over that layer, and return the whole
+    stacked cache.  ``cache_len`` lives at the model level (shared across
+    layers)."""
+    k: jax.Array
     v: jax.Array
-    # cache_len lives at the model level (shared across layers)
+
+
+def _layer(a: jax.Array, layer, rows: Optional[int] = None) -> jax.Array:
+    """Layer ``layer`` of a stacked (L, B, S, ...) cache, cut to its first
+    ``rows`` positions when given."""
+    if rows is None:
+        return lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+    start = (layer,) + (0,) * (a.ndim - 1)
+    return lax.dynamic_slice(a, start, (1, a.shape[1], rows, *a.shape[3:]))[0]
+
+
+def _layer_pages(pool: jax.Array, layer, table: jax.Array) -> jax.Array:
+    """The pages ``table`` (B, nb) names in layer ``layer`` of a stacked
+    (L, P, ps, Hkv, Dh) pool, in logical order: (B, nb·ps, Hkv, Dh) — the
+    layout ``ref.gather_pages`` gives for one layer's pool, in one gather."""
+    B, nb = table.shape
+    return pool[layer, table].reshape(B, nb * pool.shape[2], *pool.shape[3:])
 
 
 def init_attn_params(key: jax.Array, cfg: ModelConfig, dtype) -> Dict[str, jax.Array]:
@@ -52,7 +75,7 @@ def fuse_qkv_weights(p) -> jax.Array:
     """Concatenate wq/wk/wv into one (d, qd+2·kvd) matrix.  Called ONCE per
     decode dispatch on the stacked (L, ...) layer weights — outside the
     layer scan — so the concat is loop-invariant w.r.t. the token scan and
-    costs nothing per step (see transformer.run_layers_decode)."""
+    costs nothing per step (see transformer.fused_decode_weights)."""
     return jnp.concatenate([p["wq"], p["wk"], p["wv"]], axis=-1)
 
 
@@ -220,30 +243,32 @@ def attention_block(
 def attention_decode(
     p: Dict[str, jax.Array],
     x: jax.Array,                       # (B, 1, d) — one new token
-    cache: KVCache,
+    cache: KVCache,                     # stacked (L, B, S_cache, Hkv, Dh)
+    layer: jax.Array,                   # scalar int32: the layer to run
     cache_len: jax.Array,               # scalar int32 OR (B,) per-slot lengths
     cfg: ModelConfig,
     wqkv: Optional[jax.Array] = None,   # precomputed fuse_qkv_weights(p)
     page_table: Optional[jax.Array] = None,   # (B, n_blocks) int32 page ids
 ) -> Tuple[jax.Array, KVCache]:
-    """One decode step: append to cache (ring for SWA), attend, project.
+    """One decode step of layer ``layer``: write the token's KV into the
+    stacked cache (ring for SWA), attend over that layer, project.
 
     ``cache_len`` may be a scalar (fixed-batch generation: every sequence is
     at the same position) or a (B,) vector (continuous batching: each slot
-    has its own length; writes go to per-slot positions via a vmapped
-    dynamic_update_slice).  With ``cfg.use_pallas`` the attention runs the
-    flash-decoding kernel (length-skipped tiles, split-K for long caches)
-    instead of the dense einsum over the full ``max_len`` cache.
+    has its own length; writes go to per-slot positions, one row scatter).
+    With ``cfg.use_pallas`` the attention runs the flash-decoding kernel
+    (length-skipped tiles, split-K for long caches) instead of the dense
+    einsum over the full ``max_len`` cache.
 
-    With ``page_table`` the cache is the shared page pool (P, ps, Hkv, Dh):
-    the new token's KV scatters to its table-resolved (page, row) and
-    attention reads through the table — the Pallas paged kernel gathers
-    pages inside its grid; the lax fallback gathers then reuses the dense
-    reference.  Paged mode requires ragged (B,) ``cache_len`` and full
+    With ``page_table`` the cache is the stacked shared page pool (L, P,
+    ps, Hkv, Dh): the new token's KV scatters to its table-resolved (page,
+    row) and attention reads through the table — the Pallas paged kernel
+    gathers pages inside its grid; the lax fallback gathers then reuses the
+    dense reference.  Paged mode requires ragged (B,) ``cache_len`` and full
     (non-sliding-window) attention.
     """
     if page_table is not None:
-        return _attention_decode_paged(p, x, cache, cache_len, cfg,
+        return _attention_decode_paged(p, x, cache, layer, cache_len, cfg,
                                        wqkv=wqkv, page_table=page_table)
     B = x.shape[0]
     cache_len = jnp.asarray(cache_len, jnp.int32)
@@ -254,7 +279,7 @@ def attention_decode(
     )
     q, k_new, v_new = _project_qkv(p, x, cfg, positions, fused=True, wqkv=wqkv)
 
-    W = cache.k.shape[1]
+    W = cache.k.shape[2]
     if cfg.sliding_window > 0:
         write_at = cache_len % W
         eff_len = jnp.minimum(cache_len + 1, W)
@@ -263,15 +288,15 @@ def attention_decode(
         eff_len = cache_len + 1
     with jax.named_scope("kv_write"):
         if ragged:
-            k_c = jax.vmap(
-                lambda c, n, w: lax.dynamic_update_slice(c, n, (w, 0, 0))
-            )(cache.k, k_new, write_at)
-            v_c = jax.vmap(
-                lambda c, n, w: lax.dynamic_update_slice(c, n, (w, 0, 0))
-            )(cache.v, v_new, write_at)
+            # clipped like a dynamic_update_slice's start
+            slot = jnp.arange(B)
+            k_c = cache.k.at[layer, slot, write_at].set(k_new[:, 0], mode="clip")
+            v_c = cache.v.at[layer, slot, write_at].set(v_new[:, 0], mode="clip")
         else:
-            k_c = lax.dynamic_update_slice(cache.k, k_new, (0, write_at, 0, 0))
-            v_c = lax.dynamic_update_slice(cache.v, v_new, (0, write_at, 0, 0))
+            at = (layer, 0, write_at, 0, 0)
+            k_c = lax.dynamic_update_slice(cache.k, k_new[None], at)
+            v_c = lax.dynamic_update_slice(cache.v, v_new[None], at)
+    k_l, v_l = _layer(k_c, layer), _layer(v_c, layer)
 
     # ring buffer already bounds the SWA window, so only length masking
     # remains — which is exactly the flash-decoding kernel's contract.
@@ -279,9 +304,9 @@ def attention_decode(
         from repro.kernels.decode_attention.ops import decode_attention as kdecode
 
         lengths = eff_len if ragged else jnp.broadcast_to(eff_len, (B,))
-        out = kdecode(q[:, 0], k_c, v_c, lengths, block_k=math.gcd(W, 512))
+        out = kdecode(q[:, 0], k_l, v_l, lengths, block_k=math.gcd(W, 512))
     else:
-        out = layers.decode_attention(q[:, 0], k_c, v_c, eff_len, window=0)
+        out = layers.decode_attention(q[:, 0], k_l, v_l, eff_len, window=0)
     out = jnp.einsum("bq,qd->bd", out.reshape(B, cfg.q_dim), p["wo"])[:, None, :]
     return out, KVCache(k=k_c, v=v_c)
 
@@ -289,7 +314,8 @@ def attention_decode(
 def _attention_decode_paged(
     p: Dict[str, jax.Array],
     x: jax.Array,                       # (B, 1, d)
-    cache: KVCache,                     # pool: (P, ps, Hkv, Dh)
+    cache: KVCache,                     # stacked pool: (L, P, ps, Hkv, Dh)
+    layer: jax.Array,
     cache_len: jax.Array,               # (B,) per-slot lengths
     cfg: ModelConfig,
     *,
@@ -299,7 +325,7 @@ def _attention_decode_paged(
     if cfg.sliding_window > 0:
         raise ValueError("paged KV does not support sliding-window attention")
     B = x.shape[0]
-    ps = cache.k.shape[1]
+    ps = cache.k.shape[2]
     cache_len = jnp.asarray(cache_len, jnp.int32)
     if cache_len.ndim != 1:
         raise ValueError("paged decode requires (B,) per-slot cache_len")
@@ -313,20 +339,19 @@ def _attention_decode_paged(
     )[:, 0]
     row = cache_len % ps
     with jax.named_scope("kv_write"):
-        k_c = cache.k.at[page, row].set(k_new[:, 0])
-        v_c = cache.v.at[page, row].set(v_new[:, 0])
+        k_c = cache.k.at[layer, page, row].set(k_new[:, 0])
+        v_c = cache.v.at[layer, page, row].set(v_new[:, 0])
     eff_len = cache_len + 1
 
     if cfg.use_pallas:
         from repro.kernels.decode_attention.ops import decode_attention as kdecode
 
-        out = kdecode(q[:, 0], k_c, v_c, eff_len, page_table=page_table)
+        out = kdecode(q[:, 0], _layer(k_c, layer), _layer(v_c, layer), eff_len,
+                      page_table=page_table)
     else:
-        from repro.kernels.decode_attention.ref import gather_pages
-
         out = layers.decode_attention(
-            q[:, 0], gather_pages(k_c, page_table), gather_pages(v_c, page_table),
-            eff_len, window=0,
+            q[:, 0], _layer_pages(k_c, layer, page_table),
+            _layer_pages(v_c, layer, page_table), eff_len, window=0,
         )
     out = jnp.einsum("bq,qd->bd", out.reshape(B, cfg.q_dim), p["wo"])[:, None, :]
     return out, KVCache(k=k_c, v=v_c)
@@ -335,7 +360,8 @@ def _attention_decode_paged(
 def attention_mixed(
     p: Dict[str, jax.Array],
     x: jax.Array,                       # (B, Q, d) — Q new tokens per slot
-    cache: KVCache,                     # striped (B, S, Hkv, Dh) or pool (P, ps, Hkv, Dh)
+    cache: KVCache,                     # stacked (L, B, S, Hkv, Dh) or pool (L, P, ps, Hkv, Dh)
+    layer: jax.Array,                   # scalar int32: the layer to run
     cache_lens: jax.Array,              # (B,) tokens already cached per slot
     new_lens: jax.Array,                # (B,) REAL new tokens (<= Q) per slot
     cfg: ModelConfig,
@@ -344,7 +370,8 @@ def attention_mixed(
     page_table: Optional[jax.Array] = None,   # (B, n_blocks) => paged pool
     attn_window: Optional[int] = None,  # static: keys [0, attn_window) suffice
 ) -> Tuple[jax.Array, KVCache]:
-    """One mixed-batch step: every slot advances by its own ragged suffix.
+    """One mixed-batch step of layer ``layer``: every slot advances by its
+    own ragged suffix.
 
     The engine's fused prefill+decode dispatch: slot b carries
     ``(cache_lens[b], new_lens[b])`` — a decode slot has new_len 1, a
@@ -356,7 +383,7 @@ def attention_mixed(
     to the trash page), so garbage never lands
     where real KV will live before it is overwritten.  Query i attends
     causally to every position ``<= cache_lens[b] + i`` (cached prefix +
-    the chunk's earlier tokens).
+    the chunk's earlier tokens, written into the stacked cache first).
 
     ``attn_window`` is the engine's static bound on ``max(cache_lens +
     new_lens)`` this step: attention reads only the first ``attn_window``
@@ -381,50 +408,45 @@ def attention_mixed(
     valid = jnp.arange(Q, dtype=jnp.int32)[None, :] < new_lens[:, None]
 
     if page_table is not None:
-        ps = cache.k.shape[1]
+        ps = cache.k.shape[2]
         nb = page_table.shape[1]
         block = jnp.clip(positions // ps, 0, nb - 1)
         page = jnp.take_along_axis(page_table, block, axis=1)
         page = jnp.where(valid, page, 0)                 # padding -> trash page
         row = positions % ps
         with jax.named_scope("kv_write"):
-            k_c = cache.k.at[page, row].set(k_new.astype(cache.k.dtype))
-            v_c = cache.v.at[page, row].set(v_new.astype(cache.v.dtype))
+            k_c = cache.k.at[layer, page, row].set(k_new.astype(cache.k.dtype))
+            v_c = cache.v.at[layer, page, row].set(v_new.astype(cache.v.dtype))
+        read_table = (page_table if attn_window is None
+                      else page_table[:, : -(-attn_window // ps)])
     else:
         # one (Hkv, Dh) row scatter per chunk row; padding rows aim past
         # the cache and are dropped.  (A positional select over every cache
         # position, cheaper on CPU, lowers on TPU to a per-element gather
         # of the whole cache in every layer.)
-        S = cache.k.shape[1]
+        S = cache.k.shape[2]
         pos = jnp.where(valid, positions, S)
         slot = jnp.arange(B)[:, None]
         with jax.named_scope("kv_write"):
-            k_c = cache.k.at[slot, pos].set(k_new.astype(cache.k.dtype), mode="drop")
-            v_c = cache.v.at[slot, pos].set(v_new.astype(cache.v.dtype), mode="drop")
+            k_c = cache.k.at[layer, slot, pos].set(k_new.astype(cache.k.dtype), mode="drop")
+            v_c = cache.v.at[layer, slot, pos].set(v_new.astype(cache.v.dtype), mode="drop")
 
-    if page_table is not None and attn_window is not None:
-        ps = cache.k.shape[1]
-        read_table = page_table[:, : -(-attn_window // ps)]
+    if page_table is None:
+        read_table = None
+        k_r, v_r = _layer(k_c, layer, attn_window), _layer(v_c, layer, attn_window)
+    elif cfg.use_pallas:                                 # the kernel reads the table
+        k_r, v_r = _layer(k_c, layer), _layer(v_c, layer)
     else:
-        read_table = page_table
+        k_r = _layer_pages(k_c, layer, read_table)
+        v_r = _layer_pages(v_c, layer, read_table)
     if cfg.use_pallas:
         from repro.kernels.decode_attention.ops import mixed_attention
 
-        k_r = k_c if page_table is not None or attn_window is None else k_c[:, :attn_window]
-        v_r = v_c if page_table is not None or attn_window is None else v_c[:, :attn_window]
         out = mixed_attention(q, k_r, v_r, cache_lens, page_table=read_table)
     else:
-        from repro.kernels.decode_attention.ref import (
-            mixed_attention_paged_ref,
-            mixed_attention_ref,
-        )
+        from repro.kernels.decode_attention.ref import mixed_attention_ref
 
-        if page_table is not None:
-            out = mixed_attention_paged_ref(q, k_c, v_c, read_table, cache_lens)
-        else:
-            k_r = k_c if attn_window is None else k_c[:, :attn_window]
-            v_r = v_c if attn_window is None else v_c[:, :attn_window]
-            out = mixed_attention_ref(q, k_r, v_r, cache_lens)
+        out = mixed_attention_ref(q, k_r, v_r, cache_lens)
     out = jnp.einsum("bqk,kd->bqd", out.reshape(B, Q, cfg.q_dim), p["wo"])
     return out, KVCache(k=k_c, v=v_c)
 
@@ -432,12 +454,14 @@ def attention_mixed(
 def attention_prefill_paged(
     p: Dict[str, jax.Array],
     x: jax.Array,                       # (1, T, d) — the prompt suffix
-    cfg: ModelConfig,
-    pool: KVCache,                      # (P, ps, Hkv, Dh) shared page pool
+    pool: KVCache,                      # stacked shared page pool (L, P, ps, Hkv, Dh)
+    layer: jax.Array,                   # scalar int32: the layer to run
     page_row: jax.Array,                # (nb,) int32: ONE slot's block table
     start: jax.Array,                   # scalar int32: tokens already cached
+    cfg: ModelConfig,
 ) -> Tuple[jax.Array, KVCache]:
-    """Continuation prefill: extend a paged cache by T tokens in ONE step.
+    """Continuation prefill of layer ``layer``: extend a paged cache by T
+    tokens in ONE step.
 
     The prefix-hit admission path: positions [0, start) are already in the
     pool (reused pages), so only the suffix runs through the model — its KV
@@ -453,18 +477,17 @@ def attention_prefill_paged(
     hd = cfg.resolved_head_dim
     Hq, Hkv = cfg.n_heads, cfg.n_kv_heads
     G = Hq // Hkv
-    ps = pool.k.shape[1]
+    ps = pool.k.shape[2]
     pos = start + jnp.arange(T, dtype=jnp.int32)        # (T,) absolute
     q, k_new, v_new = _project_qkv(p, x, cfg, pos[None, :])
     pages = page_row[pos // ps]
     rows = pos % ps
-    k_c = pool.k.at[pages, rows].set(k_new[0].astype(pool.k.dtype))
-    v_c = pool.v.at[pages, rows].set(v_new[0].astype(pool.v.dtype))
+    with jax.named_scope("kv_write"):
+        k_c = pool.k.at[layer, pages, rows].set(k_new[0].astype(pool.k.dtype))
+        v_c = pool.v.at[layer, pages, rows].set(v_new[0].astype(pool.v.dtype))
 
-    from repro.kernels.decode_attention.ref import gather_pages
-
-    kg = gather_pages(k_c, page_row[None])[0]           # (S_max, Hkv, Dh)
-    vg = gather_pages(v_c, page_row[None])[0]
+    kg = _layer_pages(k_c, layer, page_row[None])[0]    # (S_max, Hkv, Dh)
+    vg = _layer_pages(v_c, layer, page_row[None])[0]
     qg = q[0].reshape(T, Hkv, G, hd)
     scale = 1.0 / math.sqrt(hd)
     s = jnp.einsum("thgd,shd->hgts", qg, kg,

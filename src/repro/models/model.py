@@ -27,7 +27,7 @@ MOE_AUX_WEIGHT = 0.01
 
 
 class DecoderKVCache(NamedTuple):
-    k: jax.Array   # (L, B, Sc, Hkv, Dh)
+    k: jax.Array   # (L, B, Sc, Hkv, Dh), or the page pool (L, P, ps, Hkv, Dh)
     v: jax.Array
 
 
@@ -126,9 +126,17 @@ class Model:
                 params, x, cache, cache_len, cfg, self.mesh
             )
         else:
-            x, nk, nv = transformer.run_layers_decode(
-                params, x, cache.k, cache.v, cache_len, cfg, self.mesh,
-                fused=fused, page_table=page_table,
+            if fused is None:
+                fused = transformer.fused_decode_weights(params, cfg)
+
+            def attn(p, h, kv, layer, wqkv):
+                return attention.attention_decode(
+                    p, h, kv, layer, cache_len, cfg, wqkv=wqkv,
+                    page_table=page_table)
+
+            x, nk, nv = transformer.run_layers_kv(
+                params, x, cache.k, cache.v, attn, cfg, self.mesh,
+                fused=fused,
             )
             new_cache = DecoderKVCache(k=nk, v=nv)
         logits = transformer.logits_from_hidden(params, x, cfg, self.mesh)[:, 0]
@@ -163,9 +171,17 @@ class Model:
                              f"(family {cfg.family!r}, sliding_window="
                              f"{cfg.sliding_window})")
         x = jnp.take(params["embed"], tokens, axis=0)
-        x, nk, nv = transformer.run_layers_mixed(
-            params, x, cache.k, cache.v, cache_lens, new_lens, cfg, self.mesh,
-            fused=fused, page_table=page_table, attn_window=attn_window,
+        if fused is None:
+            fused = transformer.fused_decode_weights(params, cfg)
+
+        def attn(p, h, kv, layer, wqkv):
+            return attention.attention_mixed(
+                p, h, kv, layer, cache_lens, new_lens, cfg, wqkv=wqkv,
+                page_table=page_table, attn_window=attn_window)
+
+        x, nk, nv = transformer.run_layers_kv(
+            params, x, cache.k, cache.v, attn, cfg, self.mesh,
+            fused=fused,
         )
         if all_logits:
             logits = transformer.logits_from_hidden(params, x, cfg, self.mesh)
@@ -228,9 +244,13 @@ class Model:
         if not self.supports_paged_kv:
             raise ValueError(f"{cfg.name}: paged prefill unsupported")
         x = jnp.take(params["embed"], tokens, axis=0)
-        x, nk, nv = transformer.run_layers_prefill_paged(
-            params, x, pool.k, pool.v, page_row, start, cfg, self.mesh
-        )
+
+        def attn(p, h, kv, layer, wqkv):
+            return attention.attention_prefill_paged(
+                p, h, kv, layer, page_row, start, cfg)
+
+        x, nk, nv = transformer.run_layers_kv(
+            params, x, pool.k, pool.v, attn, cfg, self.mesh)
         logits = transformer.logits_from_hidden(
             params, x[:, -1:], cfg, self.mesh
         )[:, 0]

@@ -9,7 +9,7 @@ DESIGN.md §5 so llama3-405b train_4k fits a 16 GB v5e chip.
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -128,9 +128,9 @@ def full_activation(x: jax.Array, mesh) -> jax.Array:
 
 def mlp_block(lp, h, cfg: ModelConfig, mesh):
     """Post-attention feed-forward dispatch (MoE / gelu / swiglu) shared by
-    the sequence and paged-continuation layer bodies.  Returns (m, aux).
-    The decode body keeps its own variant: it consumes the pre-fused
-    [w_gate|w_up] matrix instead of the separate weights."""
+    the sequence and cached-step layer bodies.  Returns (m, aux).  A cached
+    step with fused swiglu weights consumes the pre-fused [w_gate|w_up]
+    matrix instead (``run_layers_kv``)."""
     if cfg.is_moe:
         return moe.moe_block(lp["moe"], h, cfg, mesh)
     if cfg.mlp_type == "gelu":
@@ -206,7 +206,7 @@ def run_layers_seq(
 
 
 # ---------------------------------------------------------------------------
-# decode (single token through all layers)
+# cached steps (decode, mixed, continuation prefill) through all layers
 # ---------------------------------------------------------------------------
 
 
@@ -216,12 +216,13 @@ def fused_decode_weights(params: Dict, cfg: ModelConfig):
     w_gu = [w_gate|w_up].
 
     Call this OUTSIDE the token-generation scan (see ServingEngine) and
-    pass the result to ``run_layers_decode``: the concats then run once per
-    generate dispatch and enter the token loop as invariant operands.
-    Computing them *inside* the loop body (the default when ``fused`` is
-    None — fine for single-step callers) re-materializes the concatenated
-    matrices every token whenever the layer scan is a real while loop,
-    which measurably costs decode throughput."""
+    pass the result to ``run_layers_kv``: the concats then run once per
+    generate dispatch and enter the token loop as invariant operands, and
+    the layer loop slices layer ``i``'s rows of them as it slices the
+    other stacked weights (the KV cache, by contrast, rides the layer
+    loop's carry).  Computing them *inside* the token loop re-materializes
+    the concatenated matrices every token, which measurably costs decode
+    throughput."""
     with jax.named_scope("fuse_weights"):
         wqkv = attention.fuse_qkv_weights(params["layers"]["attn"])
         w_gu = None
@@ -232,141 +233,57 @@ def fused_decode_weights(params: Dict, cfg: ModelConfig):
     return {"wqkv": wqkv, "w_gu": w_gu}
 
 
-def run_layers_decode(
+def run_layers_kv(
     params: Dict,
-    x: jax.Array,                # (B, 1, d)
+    x: jax.Array,                # (B, T, d): the new tokens' embeddings
     cache_k: jax.Array,          # (L, B, Sc, Hkv, Dh) or paged (L, P, ps, Hkv, Dh)
     cache_v: jax.Array,
-    cache_len: jax.Array,        # scalar int32 or (B,)
+    attn: Callable,              # attn(p, h, KVCache, layer, wqkv) -> (a, KVCache)
     cfg: ModelConfig,
     mesh=None,
     fused: Optional[Dict] = None,   # fused_decode_weights(params, cfg)
-    page_table: Optional[jax.Array] = None,  # (B, n_blocks) => paged cache
 ):
-    if fused is None:
-        fused = fused_decode_weights(params, cfg)
-    xs_w = (
-        fused["wqkv"],
-        fused["w_gu"] if fused["w_gu"] is not None
-        else jnp.zeros((cfg.n_layers, 1), cache_k.dtype),
-    )
+    """The layer loop of every step that extends a KV cache: decode, the
+    mixed (chunked prefill + decode) step and continuation prefill.
 
-    def body(x, inputs):
-        lp, ck, cv, wqkv_l, wgu_l = inputs
+    The stacked cache rides the loop's carry beside the activations.  Layer
+    ``i`` writes its new rows into it at index ``i`` and attends over that
+    layer (``attn``: ``attention.attention_decode``, ``attention_mixed`` or
+    ``attention_prefill_paged`` with their other arguments bound), so no
+    per-layer copy of the cache is sliced out and no (L, ...) copy is
+    stacked back: the final carry is the new cache, and under the caller's
+    donation it is the input buffer updated in place.
+
+    ``fused`` selects the fused QKV and gate/up projections of the decode
+    hot path; without it a layer runs the plain ``mlp_block`` (the
+    continuation prefill).  Returns (x, new_k, new_v)."""
+
+    def body(carry, inputs):
+        x, k, v = carry
+        lp, i, w = inputs
         h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
         with jax.named_scope("attn"):
-            a, new_cache = attention.attention_decode(
-                lp["attn"], h, attention.KVCache(k=ck, v=cv), cache_len, cfg,
-                wqkv=wqkv_l, page_table=page_table,
-            )
+            a, kv = attn(lp["attn"], h, attention.KVCache(k=k, v=v), i,
+                         None if w is None else w["wqkv"])
         x = x + a
         h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
         with jax.named_scope("mlp"):
-            if cfg.is_moe:
-                m, _ = moe.moe_block(lp["moe"], h, cfg, mesh)
-            elif cfg.mlp_type == "gelu":
-                hu = jnp.einsum("...d,df->...f", h, lp["mlp"]["w_up"])
-                hu = jax.nn.gelu(hu.astype(jnp.float32)).astype(h.dtype)
-                m = jnp.einsum("...f,fd->...d", hu, lp["mlp"]["w_down"])
+            if w is None or w["w_gu"] is None:
+                m, _ = mlp_block(lp, h, cfg, mesh)
             else:
-                m = layers.swiglu_fused(h, wgu_l, lp["mlp"]["w_down"])
+                m = layers.swiglu_fused(h, w["w_gu"], lp["mlp"]["w_down"])
         x = x + m
-        return x, (new_cache.k, new_cache.v)
+        return (x, kv.k, kv.v), None
 
     # small unroll: decode bodies are tiny, so the layer loop's while
     # overhead is material on CPU/small models; 4 keeps HLO size bounded
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], cache_k, cache_v, *xs_w),
-        unroll=min(4, cfg.n_layers),
+    L = cfg.n_layers
+    (x, k, v), _ = lax.scan(
+        body, (x, cache_k, cache_v),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32), fused),
+        unroll=min(4, L),
     )
-    return x, new_k, new_v
-
-
-def run_layers_mixed(
-    params: Dict,
-    x: jax.Array,                # (B, Q, d) — ragged new-token suffixes
-    cache_k: jax.Array,          # (L, B, Sc, Hkv, Dh) or paged (L, P, ps, Hkv, Dh)
-    cache_v: jax.Array,
-    cache_lens: jax.Array,       # (B,) tokens already cached per slot
-    new_lens: jax.Array,         # (B,) real new tokens (<= Q) per slot
-    cfg: ModelConfig,
-    mesh=None,
-    fused: Optional[Dict] = None,   # fused_decode_weights(params, cfg)
-    page_table: Optional[jax.Array] = None,  # (B, n_blocks) => paged cache
-    attn_window: Optional[int] = None,       # static content bound (see attention_mixed)
-):
-    """The mixed-batch (chunked prefill + decode) step through the scanned
-    layer stack — ``run_layers_decode`` generalized from one token to a
-    ragged q-chunk per slot.  Returns (x, new_k, new_v)."""
-    if fused is None:
-        fused = fused_decode_weights(params, cfg)
-    xs_w = (
-        fused["wqkv"],
-        fused["w_gu"] if fused["w_gu"] is not None
-        else jnp.zeros((cfg.n_layers, 1), cache_k.dtype),
-    )
-
-    def body(x, inputs):
-        lp, ck, cv, wqkv_l, wgu_l = inputs
-        h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        with jax.named_scope("attn"):
-            a, new_cache = attention.attention_mixed(
-                lp["attn"], h, attention.KVCache(k=ck, v=cv), cache_lens,
-                new_lens, cfg, wqkv=wqkv_l, page_table=page_table,
-                attn_window=attn_window,
-            )
-        x = x + a
-        h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        with jax.named_scope("mlp"):
-            if cfg.is_moe:
-                m, _ = moe.moe_block(lp["moe"], h, cfg, mesh)
-            elif cfg.mlp_type == "gelu":
-                hu = jnp.einsum("...d,df->...f", h, lp["mlp"]["w_up"])
-                hu = jax.nn.gelu(hu.astype(jnp.float32)).astype(h.dtype)
-                m = jnp.einsum("...f,fd->...d", hu, lp["mlp"]["w_down"])
-            else:
-                m = layers.swiglu_fused(h, wgu_l, lp["mlp"]["w_down"])
-        x = x + m
-        return x, (new_cache.k, new_cache.v)
-
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], cache_k, cache_v, *xs_w),
-        unroll=min(4, cfg.n_layers),
-    )
-    return x, new_k, new_v
-
-
-def run_layers_prefill_paged(
-    params: Dict,
-    x: jax.Array,                # (1, T, d) — prompt suffix embeddings
-    pool_k: jax.Array,           # (L, P, ps, Hkv, Dh)
-    pool_v: jax.Array,
-    page_row: jax.Array,         # (nb,) int32: the slot's block table
-    start: jax.Array,            # scalar int32: cached-prefix length
-    cfg: ModelConfig,
-    mesh=None,
-):
-    """Continuation prefill through the scanned layer stack: every layer
-    extends the paged cache by the suffix and attends over prefix+suffix.
-    Returns (x, new_pool_k, new_pool_v)."""
-
-    def body(x, inputs):
-        lp, pk, pv = inputs
-        h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, new_cache = attention.attention_prefill_paged(
-            lp["attn"], h, cfg, attention.KVCache(k=pk, v=pv), page_row, start
-        )
-        x = x + a
-        h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        m, _ = mlp_block(lp, h, cfg, mesh)
-        x = x + m
-        return x, (new_cache.k, new_cache.v)
-
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], pool_k, pool_v),
-        unroll=min(4, cfg.n_layers),
-    )
-    return x, new_k, new_v
+    return x, k, v
 
 
 # ---------------------------------------------------------------------------

@@ -124,14 +124,15 @@ def run_zamba2_decode(params, x, cache: Zamba2Cache, cache_len, cfg: ModelConfig
         x, (nconv, nstate) = lax.scan(mamba_body, x, (gp, gconv, gstate))
         h = layers.rms_norm(x, shared["ln1"], cfg.norm_eps)
         a, ncache = attention.attention_decode(
-            shared["attn"], h, attention.KVCache(k=ak, v=av), cache_len, cfg
+            shared["attn"], h, attention.KVCache(k=ak[None], v=av[None]), 0,
+            cache_len, cfg,
         )
         x = x + a
         h = layers.rms_norm(x, shared["ln2"], cfg.norm_eps)
         x = x + layers.swiglu(
             h, shared["mlp"]["w_gate"], shared["mlp"]["w_up"], shared["mlp"]["w_down"]
         )
-        return x, (nconv, nstate, ncache.k, ncache.v)
+        return x, (nconv, nstate, ncache.k[0], ncache.v[0])
 
     x, (nconv, nstate, nk, nv) = lax.scan(
         group_body, x, (grouped, mconv, mstate, cache.attn_k, cache.attn_v)

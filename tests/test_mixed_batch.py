@@ -9,6 +9,8 @@ regression (pow-2 buckets => one trace serves many prompt lengths), the
 telemetry counter audit under chunked admission, and the fleet-side
 chunk-budget/TTFT-p99 plumbing.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -410,3 +412,154 @@ def test_ragged_chunk_boundaries_property(qwen):
         budget=st.sampled_from([2, 5, 8, 16]),
         seed=st.integers(0, 3),
     )(check))()
+
+
+# ---------------------------------------------------------------------------
+# the layer loop writes the carried cache only at the new positions
+# ---------------------------------------------------------------------------
+
+
+def _layer_loop_ref(model, params, x, cache, attn, fused):
+    """A plain per-layer Python loop over the same attention functions:
+    layer l's cache cut out of the stack, run as a one-layer stack, and the
+    layers stacked back — what the scanned loop computes, without the scan
+    or the carried cache."""
+    from repro.models import attention, layers, transformer
+
+    cfg = model.cfg
+    ks, vs = [], []
+    for l in range(cfg.n_layers):
+        lp = jax.tree.map(lambda a: a[l], params["layers"])
+        h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
+        one = attention.KVCache(k=cache.k[l:l + 1], v=cache.v[l:l + 1])
+        a, one = attn(lp["attn"], h, one, 0,
+                      None if fused is None else fused["wqkv"][l])
+        x = x + a
+        h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
+        if fused is None or fused["w_gu"] is None:
+            m, _ = transformer.mlp_block(lp, h, cfg, None)
+        else:
+            m = layers.swiglu_fused(h, fused["w_gu"][l], lp["mlp"]["w_down"])
+        x = x + m
+        ks.append(one.k[0])
+        vs.append(one.v[0])
+    return x, jnp.stack(ks), jnp.stack(vs)
+
+
+_LAYER_LOOP_CASES = {
+    # case: (arch, step, paged)
+    "decode-ragged": ("qwen3-0.6b", "decode", False),
+    "decode-scalar": ("qwen3-0.6b", "decode_scalar", False),
+    "decode-paged": ("qwen3-0.6b", "decode", True),
+    "mixed": ("qwen3-0.6b", "mixed", False),
+    "mixed-paged": ("qwen3-0.6b", "mixed", True),
+    "ring-mixtral": ("mixtral-8x22b", "decode", False),
+    "moe-mixed": ("arctic-480b", "mixed", False),
+    "prefill-paged": ("qwen3-0.6b", "prefill_paged", True),
+}
+
+
+@pytest.mark.parametrize("case", list(_LAYER_LOOP_CASES))
+def test_layer_loop_writes_only_new_rows(case):
+    """``run_layers_kv`` (through the ``Model`` step) against a plain
+    per-layer loop: the same logits and the same cache, and every cache row
+    but the step's new positions bit-identical to the input.  Six layers,
+    so the four-layer unroll runs as a loop with a remainder."""
+    from repro.models import attention, transformer
+
+    arch, step, paged = _LAYER_LOOP_CASES[case]
+    cfg = get_config(arch).reduce()
+    cfg = dataclasses.replace(cfg, n_layers=6)
+    model = Model(cfg)
+    params = model.init(jax.random.key(0))
+    B, S, ps, Q = 4, 64, 8, 8
+    nb = S // ps
+    kc, kt, kx = jax.random.split(jax.random.key(1), 3)
+    if paged:
+        shape = model.empty_page_pool(1 + B * nb, ps).k.shape
+    else:
+        shape = model.empty_cache(B, S).k.shape
+    W = shape[2]                                  # ring width or page size
+    cache = type(model.empty_cache(1, 1))(
+        k=jax.random.normal(kc, shape), v=jax.random.normal(kt, shape))
+    table = 1 + jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
+    table = table.at[3].set(0)                    # slot 3 idle: the trash page
+    lens = jnp.array([3, 17, 0, 40], jnp.int32)
+    if step == "decode" and cfg.sliding_window:
+        lens = jnp.array([3, 70, 0, 129], jnp.int32)   # wraps the ring
+    news = jnp.array([1, 5, 0, 8], jnp.int32)
+    fused = transformer.fused_decode_weights(params, cfg)
+    pt = table if paged else None
+
+    if step in ("decode", "decode_scalar"):
+        cl = jnp.int32(21) if step == "decode_scalar" else lens
+        tokens = jax.random.randint(kx, (B, 1), 0, cfg.vocab_size)
+        run = jax.jit(lambda c: model.decode(params, tokens, c, cl, fused=fused,
+                                             page_table=pt))
+
+        def attn(p, h, kv, layer, w):
+            return attention.attention_decode(p, h, kv, layer, cl, cfg, wqkv=w,
+                                              page_table=pt)
+
+        rows = [(b, int(jnp.broadcast_to(cl, (B,))[b])) for b in range(B)]
+        pick = lambda x: x[:, 0]
+        ref_fused = fused
+    elif step == "mixed":
+        tokens = jax.random.randint(kx, (B, Q), 0, cfg.vocab_size)
+        run = jax.jit(lambda c: model.step_mixed(
+            params, tokens, c, lens, news, fused=fused, page_table=pt,
+            attn_window=S))
+
+        def attn(p, h, kv, layer, w):
+            return attention.attention_mixed(p, h, kv, layer, lens, news, cfg,
+                                             wqkv=w, page_table=pt,
+                                             attn_window=S)
+
+        rows = [(b, int(lens[b]) + j) for b in range(B) for j in range(Q)
+                if j < int(news[b]) or paged]     # paged padding: trash page
+        last = jnp.maximum(news - 1, 0)
+        pick = lambda x: jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        ref_fused = fused
+    else:
+        start, T = 13, 9
+        tokens = jax.random.randint(kx, (1, T), 0, cfg.vocab_size)
+        run = jax.jit(lambda c: model.prefill_paged(params, tokens, c, table[1],
+                                                    jnp.int32(start)))
+
+        def attn(p, h, kv, layer, w):
+            return attention.attention_prefill_paged(p, h, kv, layer, table[1],
+                                                     jnp.int32(start), cfg)
+
+        rows = [(1, start + j) for j in range(T)]
+        pick = lambda x: x[:, -1]
+        ref_fused = None
+
+    def ref(c):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        x, k, v = _layer_loop_ref(model, params, x, c, attn, ref_fused)
+        return pick(transformer.logits_from_hidden(params, x, cfg)), k, v
+
+    want_logits, want_k, want_v = jax.jit(ref)(cache)
+    logits, new = run(cache)
+    # same math; the scan and the unrolled reference compile to different
+    # CPU fusions, which may round float32 differently in the last bits
+    tol = dict(rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits), **tol)
+    np.testing.assert_allclose(np.asarray(new.k), np.asarray(want_k), **tol)
+    np.testing.assert_allclose(np.asarray(new.v), np.asarray(want_v), **tol)
+
+    # the step's new positions, as (slot or page, row) of one layer
+    written = np.zeros(shape[1:3], bool)
+    for b, pos in rows:
+        if paged:
+            padding = step == "mixed" and pos >= int(lens[b]) + int(news[b])
+            page = 0 if padding else int(table[b, pos // ps])
+            written[page, pos % ps] = True
+        else:
+            written[b, pos % W] = True
+    old_k, old_v = np.asarray(cache.k), np.asarray(cache.v)
+    new_k, new_v = np.asarray(new.k), np.asarray(new.v)
+    np.testing.assert_array_equal(new_k[:, ~written], old_k[:, ~written])
+    np.testing.assert_array_equal(new_v[:, ~written], old_v[:, ~written])
+    assert (new_k[:, written] != old_k[:, written]).any(axis=(-2, -1)).all()
+    assert (new_v[:, written] != old_v[:, written]).any(axis=(-2, -1)).all()

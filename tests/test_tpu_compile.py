@@ -1,12 +1,13 @@
-"""Chip-compiler rehearsals: the served path's attention kernels, and one
-whole mixed engine step, compiled for a described (not attached) TPU v5e
-at qwen3-0.6b's published widths.
+"""Chip-compiler rehearsals: the served path's attention kernels, and the
+engine's whole step programs, compiled for a described (not attached) TPU
+v5e at qwen3-0.6b's published widths.
 
 Interpret mode accepts BlockSpecs that the TPU's Mosaic compiler refuses,
 so the CPU kernel tests alone cannot show that a kernel will run on the
 chip.  These tests lower each kernel with ``interpret=False`` for one
-v5e chip and check that a ``tpu_custom_call`` reaches the compiled HLO.
-Nothing runs: they say nothing about results or speed.
+v5e chip and check that a ``tpu_custom_call`` reaches the compiled HLO;
+the step programs' memory and traffic estimates show how the KV cache is
+updated.  Nothing runs: they say nothing about results or speed.
 
 The topology is described inside a module fixture (never at import): only
 one process may load the TPU library at a time, and under pytest-xdist
@@ -151,7 +152,53 @@ def test_full_width_mixed_step_compiles_for_v5e(paged, use_pallas, one_chip,
     mem = compiled.memory_analysis()
     # arguments (weights + KV) and temporaries fit one 16 GB chip
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
-    # the compiler's traffic estimate stays near weights + KV + attention
-    # (about 1.1e10 B); a KV write that gathers the whole cache element by
-    # element in every layer reads 3.6e11
-    assert compiled.cost_analysis()["bytes accessed"] < 3e10
+    # the compiler's traffic estimate (a while loop's body counted once)
+    # reads 2.8e9-3.1e9 B with the stacked cache carried through the layer
+    # loop and written in place; slicing each layer's cache out of the
+    # stack and stacking a new copy back read 8.5e9-9.2e9, and a KV write
+    # that gathers the whole cache element by element in every layer 3.6e11
+    assert compiled.cost_analysis()["bytes accessed"] < 5e9
+
+
+@pytest.mark.parametrize("program", ["chunk_scan", "mixed_step"])
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+def test_step_programs_hold_no_second_cache(paged, program, one_chip):
+    """The engine's decode chunk and mixed step at qwen3-0.6b's published
+    width and depth, 14 slots of 2048 tokens, XLA attention: the stacked KV
+    cache rides the layer loop and is written in place, so the compiler's
+    temporaries hold no copy of it.  They come to about 0.7 GB, mostly the
+    fused projection weights; a layer loop that slices each layer's cache
+    out of the stack and stacks a new copy back reserves 5.1-5.4 GB, more
+    than the whole 3.29 GB cache."""
+    from repro.models import Model
+    from repro.serving.engine import EngineConfig, ServingEngine
+
+    model = Model(get_config("qwen3-0.6b"))
+    n, Q, max_len, steps = 14, 32, 2048, 8
+    eng = ServingEngine(model, None, EngineConfig(
+        max_len=max_len, decode_batch=n, paged_kv=paged, page_size=PS,
+        num_pages=1 + n * max_len // PS))
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+    params = on_chip(model.param_specs())
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    active = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)
+    if paged:
+        cache = on_chip(jax.eval_shape(
+            lambda: model.empty_page_pool(eng.num_pages, PS)))
+        table = (i32(n, eng.max_blocks),)
+    else:
+        cache = on_chip(model.cache_specs(n, max_len))
+        table = ()
+    if program == "chunk_scan":
+        key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+        fn = eng._chunk_paged if paged else eng._chunk
+        lowered = fn.lower(params, cache, *table, i32(n), i32(n), active, key,
+                           steps)
+    else:
+        fn = eng._mixed_paged if paged else eng._mixed
+        lowered = fn.lower(params, cache, *table, i32(n, Q), i32(n), i32(n),
+                           i32(n), active, max_len)
+    mem = lowered.compile().memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.temp_size_in_bytes < cache_bytes / 2
