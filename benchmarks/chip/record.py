@@ -3,15 +3,15 @@
 
 The harness fills a ``Window`` from its own clock (due times, tick spans,
 every token as the client received it), from the program's tracer events
-(``engine.pump``, ``req.dispatched``) and, in a traced run, from the
-reduced device trace.  Times are ``time.perf_counter`` seconds.
+(``engine.pump``, ``req.dispatched``, ``req.admitted``), from the
+program's counters at the window's open and at the loop's end and, in a
+traced run, from the reduced device trace.  Times are
+``time.perf_counter`` seconds.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
-
-from benchmarks.chip import work
+from typing import Any, Dict, List, Optional, Tuple
 
 
 @dataclass
@@ -57,7 +57,7 @@ class Window:
     seconds: float
     open: float
     close: float                    # open + seconds
-    shapes: work.Shapes
+    shapes: Any                     # the architecture's work model (work.py)
     decode_chunk: int
     setup_s: float = 0.0            # process start to the window's open
     peak_flops: float = 0.0
@@ -67,6 +67,8 @@ class Window:
     ticks: List[Tick] = field(default_factory=list)
     pumps: List[Pump] = field(default_factory=list)
     dispatched_t: Dict[int, float] = field(default_factory=dict)
+    admitted_prompt_tokens: int = 0  # prompts given a slot in the ticks
+    counters: Dict[str, int] = field(default_factory=dict)  # run.program_counters
     trace: Optional[dict] = None    # trace_reduce.reduce(), traced runs only
     trace_end: float = 0.0          # end of the traced span (the loop's end)
 

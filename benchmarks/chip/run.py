@@ -13,12 +13,16 @@ mix (``traffic/<mix>.json``).  In one process the harness
      ``JAX_COMPILATION_CACHE_DIR`` says;
   3. makes the weights on the device from ``--seed`` and builds the fleet
      through ``FleetRuntime`` (two one-replica tiers, as configured);
+     what belongs to the architecture (its work model, the program's
+     model fields, its weights, its reference) comes from the module
+     ``archs/<model_type>.py`` that the configuration's ``model_type``
+     names;
   4. warms up with the runtime's own ``warmup()``;
   5. releases each request through ``FleetClient.submit`` when it is due on
      the wall clock, ticks the fleet back to back, and stamps every token
      as the client receives it, for exactly ``--seconds``;
-  6. checks a sample of the finished requests against a float32 reference
-     forward (``reference.py``) and prints the result as its last line.
+  6. checks a sample of the finished requests against the architecture's
+     float32 reference forward and prints the result as its last line.
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` takes a
 profiler trace of the window and reports its per-layer metrics
@@ -26,9 +30,9 @@ profiler trace of the window and reports its per-layer metrics
 
 ``--control 1`` puts the control in the program's place for the check:
 each checked request's served tokens are replaced by the tokens that the
-float8 reference (``reference.py``) puts first at the same positions,
-and the same ``correct`` is decided on them.  It has to read false.  A
-benchmark run leaves it at 0.
+float8 control of the architecture's reference (``served_gaps``) puts
+first at the same positions, and the same ``correct`` is decided on them.
+It has to read false.  A benchmark run leaves it at 0.
 """
 from __future__ import annotations
 
@@ -38,6 +42,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -55,7 +60,7 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from benchmarks.chip import record, reference, traffic, work  # noqa: E402
+from benchmarks.chip import record, traffic  # noqa: E402
 
 SPANS = ("window", "submit", "tick", "wait_for_arrival")
 # served tokens compared with the reference in each run: at least this
@@ -81,6 +86,17 @@ def find(items, name: str, what: str) -> dict:
     raise SystemExit(f"no {what} named {name!r} in BENCHMARK.json")
 
 
+@functools.cache
+def _load_module(path: str, prefix: str):
+    """The module at ``path``, loaded once per process."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(
+        prefix + stem.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_metric(name: str):
     """The reader module ``metrics/<name>.py``; a metric ``<q>.<cells>``
     with no file of its own (one quantity reported under another name for
@@ -88,11 +104,20 @@ def load_metric(name: str):
     path = os.path.join(HERE, "metrics", f"{name}.py")
     if not os.path.exists(path) and "." in name:
         path = os.path.join(HERE, "metrics", name.rsplit(".", 1)[0] + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _load_module(path, "chipbench_metric_")
+
+
+def load_arch(config: dict):
+    """The architecture module ``archs/<model_type>.py`` of a
+    configuration: its work model, model fields, weights and reference
+    (the interface is stated in ``work.py``)."""
+    mt = config["model_type"]
+    path = os.path.join(HERE, "archs", f"{mt}.py")
+    if not os.path.exists(path):
+        raise SystemExit(f"configuration {config.get('name')!r} names "
+                         f"model_type {mt!r}, and there is no architecture "
+                         f"module {os.path.relpath(path, ROOT)}")
+    return _load_module(path, "chipbench_arch_")
 
 
 def cell_metrics(bench: dict, cell: str, trace: bool) -> list:
@@ -168,21 +193,6 @@ class ClientSink:
 
 
 # -- configuration -----------------------------------------------------------
-def model_overrides(config: dict) -> dict:
-    """The program's ``ModelConfig`` fields, as the configuration file
-    states them (the file is the configuration as run)."""
-    return dict(
-        n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        qk_norm=True, rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        dtype=config["torch_dtype"], use_pallas=False,
-    )
-
-
 def make_tiers(config: dict, seed: int):
     from repro.fleet.runtime import TierSpec
 
@@ -191,7 +201,7 @@ def make_tiers(config: dict, seed: int):
         raise ValueError(f"attention {sv['attention']!r}: only 'xla' is built")
     common = dict(
         arch=sv["arch"], reduced=False, param_seed=seed,
-        model_overrides=model_overrides(config),
+        model_overrides=load_arch(config).overrides(config),
         max_len=sv["max_len"], decode_batch=sv["decode_batch"],
         decode_chunk=sv["decode_chunk"], queue_limit=sv["queue_limit"],
         prefill_chunk=sv["prefill_chunk"],
@@ -235,7 +245,8 @@ def build_fleet(config: dict, seed: int, params):
             (a.shape, a.dtype) != (b.shape, b.dtype)
             for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(have))):
         raise RuntimeError("the program's parameter layout differs from the "
-                           "benchmark's weights (weights.py)")
+                           "benchmark's weights (archs/"
+                           f"{config['model_type']}.py make_weights)")
     rt = FleetRuntime(tiers, [], FleetConfig(seed=seed))
     rt._model_cache[(spec.arch, spec.param_seed, spec.reduced,
                      tuple(sorted(overrides.items())))] = (model, params)
@@ -247,6 +258,41 @@ def build_fleet(config: dict, seed: int, params):
 
 # -- the measured window -----------------------------------------------------
 WARM_REQUESTS = 4
+
+
+def new_window(config: dict, seconds: float) -> record.Window:
+    """An empty window record with the architecture's work model and, on a
+    TPU, the chip's published peaks."""
+    import jax
+
+    from benchmarks.chip import peaks
+
+    w = record.Window(seconds=float(seconds), open=0.0, close=0.0,
+                      shapes=load_arch(config).shapes(config),
+                      decode_chunk=config["serving"]["decode_chunk"])
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        pk = peaks.peak_for(dev.device_kind)
+        w.peak_flops, w.peak_bytes_s = pk.flops, pk.hbm_bytes_s
+    return w
+
+
+def set_up(config: dict, mix: dict, seed: int, reqs=()):
+    """The weights made from the seed, the fleet serving them, warmed up
+    for the mix and holding what the window's sessions served before it
+    (``serve_earlier``): (params, runtime, client)."""
+    import jax
+
+    from repro.fleet.client import FleetClient
+
+    arch = load_arch(config)
+    params = arch.make_weights(config, seed)
+    jax.block_until_ready(params)
+    rt = build_fleet(config, seed, params)
+    client = FleetClient(rt)
+    warm_up(rt, client, mix, seed, arch.shapes(config).vocab, config["serving"])
+    serve_earlier(client, reqs)
+    return params, rt, client
 
 
 def warm_up(rt, client, mix: dict, seed: int, vocab: int, sv: dict) -> None:
@@ -266,14 +312,42 @@ def warm_up(rt, client, mix: dict, seed: int, vocab: int, sv: dict) -> None:
     client.drain()
 
 
+def serve_earlier(client, reqs) -> None:
+    """Serve, in due order, the previous turn's prompt of every request
+    that continues a session (``Request.earlier``), one token each, and
+    drain: the cache then holds what those turns left in it."""
+    from repro.serving.api import InferenceRequest
+
+    earlier = [r for r in reqs if r.earlier]
+    for r in earlier:
+        client.submit(InferenceRequest(prompt=r.prompt[:r.earlier], max_new=1))
+    if earlier:
+        client.drain()
+
+
+def program_counters(rt) -> Dict[str, int]:
+    """Counters the program keeps, summed over its tiers: prompt tokens
+    its engines served from cached pages, requests its dispatcher placed,
+    and those it placed by cached prefix."""
+    d = rt.dispatcher
+    engines = {id(r.session.eng): r.session.eng
+               for reps in rt.replicas.values() for r in reps if r.session}
+    return {"reused_tokens": sum(e.telemetry.reused_tokens
+                                 for e in engines.values()),
+            "dispatched": sum(d.dispatched_per_tier.values()),
+            "affinity_placements": d.affinity_placements}
+
+
 def drive(rt, client, reqs, w: record.Window, span,
           prompts: Dict[int, np.ndarray]) -> dict:
-    """Release ``reqs`` when due, tick back to back, for ``w.seconds``."""
+    """Release ``reqs`` when due, tick back to back, for ``w.seconds``;
+    ``w.counters`` gets what the program's counters gained meanwhile."""
     from repro.serving.api import InferenceRequest
 
     sink = ClientSink(w)
     rt.attach_sink(sink)
     late, i, n = [], 0, len(reqs)
+    c0 = program_counters(rt)
     with span("window"):
         w.open = time.perf_counter()
         w.close = w.open + w.seconds
@@ -304,6 +378,7 @@ def drive(rt, client, reqs, w: record.Window, span,
                 with span("wait_for_arrival"):
                     time.sleep(max(0.0, min(nxt, w.close) - time.perf_counter()))
         end = time.perf_counter()
+    w.counters = {k: v - c0[k] for k, v in program_counters(rt).items()}
     return {"late_s": late, "loop_end": end}
 
 
@@ -319,6 +394,8 @@ def read_events(rt, w: record.Window) -> dict:
             w.pumps.append(record.Pump(ev["t"], ev["wall_s"], ev["occupancy"]))
         elif name == "req.dispatched":
             w.dispatched_t.setdefault(ev["rid"], ev["t"])
+        elif name == "req.admitted" and ev["rid"] in w.served:
+            w.admitted_prompt_tokens += w.served[ev["rid"]].prompt_len
         elif name == "engine.compile":
             compiles += ev.get("new_traces", 1)
     return {"engine_compile_events": compiles,
@@ -357,13 +434,12 @@ def check(params, config: dict, w: record.Window, prompts: Dict[int, np.ndarray]
     """The widest gap by which a served token's logit lies below the float32
     reference's best, over the picked requests; with ``control`` also the
     gap of the tokens the float8 control puts first."""
-    s = w.shapes
+    arch = load_arch(config)
     gaps, cgaps, n = [], [], 0
     for rid in picked:
         served = np.asarray(w.served[rid].tokens, np.int32)
-        g, cg = reference.served_gaps(
-            params, s, float(config["rms_norm_eps"]), float(config["rope_theta"]),
-            prompts[rid], served, config["serving"]["max_len"], control=control)
+        g, cg = arch.served_gaps(params, config, prompts[rid], served,
+                                 config["serving"]["max_len"], control=control)
         gaps.append(float(np.max(g)))
         if cg is not None:
             cgaps.append(float(np.max(cg)))
@@ -396,25 +472,14 @@ def run_cell(bench: dict, cell: dict, config: dict, mix: dict, seed: int,
     own gap goes to the readings)."""
     import jax
 
-    from benchmarks.chip import peaks, trace_reduce, weights
-    from repro.fleet.client import FleetClient
+    from benchmarks.chip import trace_reduce
 
     counter = CompileCounter()
     dev = jax.devices()[0]
-    s = work.shapes_of(config)
-    sv = config["serving"]
-    w = record.Window(seconds=float(seconds), open=0.0, close=0.0, shapes=s,
-                      decode_chunk=sv["decode_chunk"])
-    if dev.platform == "tpu":
-        pk = peaks.peak_for(dev.device_kind)
-        w.peak_flops, w.peak_bytes_s = pk.flops, pk.hbm_bytes_s
-
-    params = weights.make_weights(s, seed, config["torch_dtype"])
-    jax.block_until_ready(params)
-    rt = build_fleet(config, seed, params)
-    client = FleetClient(rt)
-    warm_up(rt, client, mix, seed, s.vocab, sv)
-    reqs = traffic.generate(mix, seed, seconds, s.vocab, sv["max_len"])
+    w = new_window(config, seconds)
+    reqs = traffic.generate(mix, seed, seconds, w.shapes.vocab,
+                            config["serving"]["max_len"])
+    params, rt, client = set_up(config, mix, seed, reqs)
     prompts = {}
 
     span = (lambda name: jax.profiler.TraceAnnotation(name)) if trace else (
@@ -449,6 +514,8 @@ def run_cell(bench: dict, cell: dict, config: dict, mix: dict, seed: int,
         "memory_peak_bytes": peak_bytes,
         "setup_s": setup_s,
         "persistent_cache_hits": counter.hits,
+        "program_counters": w.counters,
+        "admitted_prompt_tokens": w.admitted_prompt_tokens,
     }
     for sr in w.served.values():
         if sr.replica:

@@ -41,7 +41,7 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
         sys.path.insert(0, _p)
 
 from benchmarks.chip import (  # noqa: E402
-    program_spans, record, run, trace_reduce, traffic, work)
+    program_spans, run, trace_reduce, traffic)
 
 
 def span_cost_us(n: int = 20000) -> dict:
@@ -95,24 +95,12 @@ def traced_window(cell: dict, config: dict, mix: dict, seed: int,
                   seconds: float, bench: dict) -> dict:
     import jax
 
-    from benchmarks.chip import peaks, weights
-    from repro.fleet.client import FleetClient
-
     counter = run.CompileCounter()
     dev = jax.devices()[0]
-    s = work.shapes_of(config)
-    sv = config["serving"]
-    w = record.Window(seconds=float(seconds), open=0.0, close=0.0, shapes=s,
-                      decode_chunk=sv["decode_chunk"])
-    if dev.platform == "tpu":
-        pk = peaks.peak_for(dev.device_kind)
-        w.peak_flops, w.peak_bytes_s = pk.flops, pk.hbm_bytes_s
-    params = weights.make_weights(s, seed, config["torch_dtype"])
-    jax.block_until_ready(params)
-    rt = run.build_fleet(config, seed, params)
-    client = FleetClient(rt)
-    run.warm_up(rt, client, mix, seed, s.vocab, sv)
-    reqs = traffic.generate(mix, seed, seconds, s.vocab, sv["max_len"])
+    w = run.new_window(config, seconds)
+    reqs = traffic.generate(mix, seed, seconds, w.shapes.vocab,
+                            config["serving"]["max_len"])
+    _, rt, client = run.set_up(config, mix, seed, reqs)
 
     tdir = tempfile.mkdtemp(prefix="chipbench_spans_")
     jax.profiler.start_trace(tdir)

@@ -5,11 +5,13 @@ rates over the cell's open-loop mix.  Not part of a benchmark run.
     python3 benchmarks/chip/sweep.py --workload qwen3-0.6b.chat \
         --rates 1,2,4,8 --seconds 30 --seed 7 [--out sweep.json]
 
-For each rung the harness's own window loop runs at that rate; then the
-fleet is ticked until idle before the next rung.  A rung reports the
+For each rung the earlier turns of its sessions are served (as a run's
+set-up does) and the harness's own window loop runs at that rate; then
+the fleet is ticked until idle before the next rung.  A rung reports the
 requests due and finished, the outstanding requests at each quarter of
 the window (a backlog that grows across the window is past the knee),
-and the window's TTFT and TPOT tails.
+the window's TTFT and TPOT tails, and its shares of prompt tokens served
+from cache and of requests placed by cached prefix.
 """
 from __future__ import annotations
 
@@ -23,7 +25,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-from benchmarks.chip import record, run, traffic, work  # noqa: E402
+from benchmarks.chip import record, run, traffic  # noqa: E402
 
 
 def outstanding(w: record.Window, t: float) -> int:
@@ -32,13 +34,12 @@ def outstanding(w: record.Window, t: float) -> int:
 
 
 def rung(rt, client, config, mix, rate, seed, seconds) -> dict:
-    s = work.shapes_of(config)
-    m = dict(mix, rate_rps=rate)
-    reqs = traffic.generate(m, seed, seconds, s.vocab,
-                            config["serving"]["max_len"])
-    w = record.Window(seconds=float(seconds), open=0.0, close=0.0, shapes=s,
-                      decode_chunk=config["serving"]["decode_chunk"])
+    w = run.new_window(config, seconds)
+    reqs = traffic.generate(dict(mix, rate_rps=rate), seed, seconds,
+                            w.shapes.vocab, config["serving"]["max_len"])
+    run.serve_earlier(client, reqs)
     run.drive(rt, client, reqs, w, lambda name: contextlib.nullcontext(), {})
+    run.read_events(rt, w)
     q = [outstanding(w, w.open + f * seconds) for f in (0.25, 0.5, 0.75, 1.0)]
     out = {"rate_rps": rate, "due": len(w.served),
            "finished": sum(1 for x in w.served.values()
@@ -46,7 +47,7 @@ def rung(rt, client, config, mix, rate, seed, seconds) -> dict:
            "outstanding_at_quarters": q,
            "ticks": len(w.in_window_ticks())}
     for name in ("ttft_p50_s", "tpot_p90_ms", "out_tok_s", "tick_host_ms",
-                 "pump_ms"):
+                 "pump_ms", "prefix_reused_share", "affinity_share"):
         out[name] = run.load_metric(name).read(w)
     t0 = time.perf_counter()
     while rt.busy:
@@ -72,18 +73,11 @@ def main(argv=None) -> int:
                                      cell["traffic"] + ".json"))
     import jax
 
-    from benchmarks.chip import weights
-    from repro.fleet.client import FleetClient
-
     if jax.devices()[0].platform != "tpu":
         print("sweep.py: needs a TPU", file=sys.stderr)
         return 2
     run.use_compile_cache()
-    s = work.shapes_of(config)
-    params = weights.make_weights(s, args.seed, config["torch_dtype"])
-    rt = run.build_fleet(config, args.seed, params)
-    client = FleetClient(rt)
-    run.warm_up(rt, client, mix, args.seed, s.vocab, config["serving"])
+    _, rt, client = run.set_up(config, mix, args.seed)
     rungs = []
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         rungs.append(rung(rt, client, config, mix, rate, args.seed + i,

@@ -2,13 +2,14 @@
 does after its look for a chip -- weights from the seed, the fleet, the
 warm-up, the timed window at the mix's load, the metric readers and the
 check against the float32 reference -- and the float8 control put in the
-program's place, which the same check has to judge not correct."""
+program's place, which the same check has to judge not correct.  Every
+cell of ``BENCHMARK.json`` gets one."""
 import pytest
 
-from bench_tiny import tiny_cell
+from bench_tiny import BENCH, tiny_cell
 from benchmarks.chip import run
 
-CELLS = ["qwen3-0.6b.chat", "qwen3-4b.offline"]
+CELLS = [c["name"] for c in BENCH["workloads"]]
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -16,7 +17,7 @@ def test_run_is_correct_and_the_control_is_not(name):
     bench, cell, config, mix = tiny_cell(name)
     out = run.run_cell(bench, cell, config, mix, 2**31 + 5, 4.0, False,
                        control=True)
-    line, r = out["line"], out["readings"]
+    line, r, w = out["line"], out["readings"], out["window"]
     limits = config["check_limits"]
     # the control, judged in the program's place, is not correct ...
     assert line["correct"] is False, line["checks"]
@@ -31,8 +32,13 @@ def test_run_is_correct_and_the_control_is_not(name):
     want = {m["name"] for m in run.cell_metrics(bench, name, trace=False)}
     assert set(line["metrics"]) == want
     # both tiers' cache layouts served requests and are in the check
-    replicas = {out["window"].served[rid].replica for rid in out["picked"]}
+    replicas = {w.served[rid].replica for rid in out["picked"]}
     assert len(replicas) == 2
+    # what the cell's per-layer metrics read from the program's spans and
+    # counters is there in an untraced run too
+    for m in run.cell_metrics(bench, name, trace=True):
+        if m["source"] != "device_trace":
+            assert run.load_metric(m["name"]).read(w) > 0, m["name"]
 
 
 def test_traced_run_reports_the_host_side_layers():

@@ -1,14 +1,17 @@
-"""The check catches a broken timed path: a tiny run with the program
-broken underneath the harness has to come out as not correct.  The
-faults a served cell can have: a decode step that returns its KV state
-unchanged, and a token altered where it is produced.  (A single-chip
-serving cell has no exchange between chips and no batch mean to halve.)"""
+"""The check catches a broken timed path: a tiny run of every cell with
+the program broken underneath the harness has to come out as not
+correct.  The faults a served cell can have: a decode step that returns
+its KV state unchanged, and a token altered where it is produced; a cell
+whose prompts share prefixes also a prefix hit that hands over the wrong
+cached pages.  (A single-chip serving cell has no exchange between chips
+and no batch mean to halve.)"""
 import jax.numpy as jnp
 import pytest
 
-from bench_tiny import tiny_cell
+from bench_tiny import BENCH, tiny_cell
 from benchmarks.chip import run
 from repro.serving import engine as eng_mod
+from repro.serving.paged_kv import BlockAllocator
 
 
 def _kv_not_written(monkeypatch):
@@ -28,11 +31,36 @@ def _token_altered(monkeypatch):
     monkeypatch.setattr(eng_mod.ServingEngine, "_sample", shifted)
 
 
-@pytest.mark.parametrize("fault", [_kv_not_written, _token_altered],
-                         ids=["state_unchanged", "token_altered"])
-def test_broken_timed_path_is_not_correct(fault, monkeypatch):
-    fault(monkeypatch)
-    bench, cell, config, mix = tiny_cell("qwen3-0.6b.chat")
+def _wrong_prefix_pages(monkeypatch):
+    """A block-aligned prefix hit whose first page is another cached
+    block's: the match length is right, the KV under it is not."""
+    orig = BlockAllocator.match_prefix
+
+    def wrong(self, tokens):
+        m, pages = orig(self, tokens)
+        others = sorted(set(self._blocks.values()) - set(pages))
+        if m and others:
+            pages = [others[0]] + list(pages[1:])
+        return m, pages
+    monkeypatch.setattr(BlockAllocator, "match_prefix", wrong)
+
+
+FAULTS = {"state_unchanged": _kv_not_written, "token_altered": _token_altered,
+          "wrong_prefix_pages": _wrong_prefix_pages}
+
+
+def _cases():
+    for c in BENCH["workloads"]:
+        mix = run.load_json(f"{run.HERE}/traffic/{c['traffic']}.json")
+        for fault in FAULTS:
+            if fault != "wrong_prefix_pages" or mix["arrivals"] == "sessions":
+                yield pytest.param(c["name"], fault, id=f"{c['name']}-{fault}")
+
+
+@pytest.mark.parametrize("name, fault", list(_cases()))
+def test_broken_timed_path_is_not_correct(name, fault, monkeypatch):
+    FAULTS[fault](monkeypatch)
+    bench, cell, config, mix = tiny_cell(name)
     out = run.run_cell(bench, cell, config, mix, 23, 4.0, False)
     checks = out["line"]["checks"]
     assert out["line"]["correct"] is False

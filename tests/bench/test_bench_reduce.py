@@ -6,8 +6,8 @@ import os
 
 import pytest
 
-from bench_tiny import BENCH
-from benchmarks.chip import record, run, trace_reduce, work
+from bench_tiny import BENCH, tiny_config
+from benchmarks.chip import record, run, trace_reduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -81,9 +81,10 @@ def test_load_reads_host_spans_of_a_recorded_trace(tmp_path):
 
 
 def _window():
-    s = work.Shapes(2, 128, 4, 2, 32, 256, 512)
-    w = record.Window(seconds=10.0, open=100.0, close=110.0, shapes=s,
-                      decode_chunk=4)
+    cfg = tiny_config(run.load_json(os.path.join(
+        run.HERE, "configs", BENCH["configs"][0]["name"] + ".json")))
+    w = record.Window(seconds=10.0, open=100.0, close=110.0,
+                      shapes=run.load_arch(cfg).shapes(cfg), decode_chunk=4)
     # ten requests due a second apart; request i's first token comes
     # i/10 s after it is due, then a token every 20 ms (+1 ms per i)
     for i in range(10):
