@@ -150,9 +150,9 @@ def engine_logits(eng, prompts: np.ndarray, nxt: np.ndarray):
         aw = min(max(1 << (s + piece.shape[1] - 1).bit_length(), Q), eng.cfg.max_len)
         args = (jnp.asarray(chunks), tok, jnp.asarray(lens), jnp.asarray(new), isd, aw)
         if eng.paged:
-            logits, cache, _ = eng._mixed_paged(eng.params, cache, tables, *args)
+            logits, cache = eng._mixed_paged(eng.params, cache, tables, *args)[:2]
         else:
-            logits, cache, _ = eng._mixed(eng.params, cache, *args)
+            logits, cache = eng._mixed(eng.params, cache, *args)[:2]
         # finish each step before the next, as a served pump does: on the
         # CPU backend, back-to-back steps that donate the cache now and
         # then returned wrong logits

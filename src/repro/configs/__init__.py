@@ -30,6 +30,7 @@ _ARCH_MODULES = {
     "rwkv6-7b": "repro.configs.rwkv6_7b",
     "zamba2-2.7b": "repro.configs.zamba2_2_7b",
     "llava-next-mistral-7b": "repro.configs.llava_next_mistral_7b",
+    "mellum2-12b-a2.5b": "repro.configs.mellum2_12b_a2_5b",
 }
 
 ARCH_IDS: Tuple[str, ...] = tuple(_ARCH_MODULES)

@@ -33,7 +33,16 @@ class ModelConfig:
     head_dim: int = 0                # explicit (qwen3-style); 0 => d_model//n_heads
     qk_norm: bool = False
     rope_theta: float = 10_000.0
-    sliding_window: int = 0          # 0 => full attention
+    sliding_window: int = 0          # 0 => full attention; >0 => every layer
+                                     # windowed over a ring cache (mixtral)
+    # per-layer attention kinds (mellum): "sliding_attention" layers attend
+    # over the last ``layer_window`` positions, "full_attention" layers over
+    # all of them; every layer keeps a full-length cache.  () => all alike.
+    layer_types: Tuple[str, ...] = ()
+    layer_window: int = 0
+    # the full-attention layers' YaRN RoPE: (factor, original max positions,
+    # beta_fast, beta_slow, attention_factor); () => default RoPE everywhere
+    rope_yarn: Tuple[float, ...] = ()
     causal: bool = True              # False for encoder-only
     mlp_type: str = "swiglu"         # "swiglu" (3 mats) | "gelu" (2 mats)
     # --- MoE ---------------------------------------------------------------
@@ -42,6 +51,10 @@ class ModelConfig:
     moe_dense_residual: bool = False  # Arctic: dense FFN residual in parallel
     dense_residual_ff: int = 0        # width of Arctic's parallel dense FFN
     capacity_factor: float = 1.25
+    # the experts whose weights live here: ids [expert_offset, expert_offset
+    # + experts_held) of the router's n_experts (0 => all of them)
+    experts_held: int = 0
+    expert_offset: int = 0
     # --- SSM / RWKV --------------------------------------------------------
     ssm_state: int = 0               # Mamba2 state size per head
     ssm_head_dim: int = 64           # Mamba2 P (head channel dim)
@@ -64,6 +77,24 @@ class ModelConfig:
     use_pallas: bool = False     # Pallas kernels for attention/scan hot-spots
                                  # (TPU target; interpret=True on CPU)
 
+    def __post_init__(self):
+        if self.layer_types:
+            if len(self.layer_types) != self.n_layers:
+                raise ValueError(f"{self.name}: {len(self.layer_types)} layer types "
+                                 f"for {self.n_layers} layers")
+            bad = set(self.layer_types) - {"sliding_attention", "full_attention"}
+            if bad:
+                raise ValueError(f"{self.name}: unknown layer types {sorted(bad)}")
+            if "sliding_attention" in self.layer_types and self.layer_window <= 0:
+                raise ValueError(f"{self.name}: sliding layers need layer_window > 0")
+            if self.sliding_window:
+                raise ValueError(f"{self.name}: per-layer windows keep full caches; "
+                                 "the ring window (sliding_window) is another path")
+        if self.expert_offset + self.n_experts_held > self.n_experts:
+            raise ValueError(f"{self.name}: experts [{self.expert_offset}, "
+                             f"{self.expert_offset + self.n_experts_held}) not among "
+                             f"{self.n_experts}")
+
     # -- derived ------------------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
@@ -85,6 +116,24 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def n_experts_held(self) -> int:
+        """Experts whose weights this model holds (the router spans all
+        ``n_experts``)."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def layer_windows(self) -> Tuple[int, ...]:
+        """Each layer's attention window from ``layer_types`` (0 = full)."""
+        return tuple(self.layer_window if t == "sliding_attention" else 0
+                     for t in self.layer_types)
+
+    @property
+    def has_layer_kinds(self) -> bool:
+        """Layers differ in window or RoPE, so the layer loop carries each
+        layer's kind beside its weights."""
+        return any(self.layer_windows) or bool(self.layer_types and self.rope_yarn)
+
     def param_count(self) -> int:
         """Analytic parameter count (used for 6ND model-FLOPs and sanity)."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
@@ -96,7 +145,8 @@ class ModelConfig:
         if self.family in ("dense", "moe", "encoder", "vlm"):
             attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
             if self.is_moe:
-                ffn = self.n_experts * 3 * d * f + d * self.n_experts  # router
+                # the held experts' weights, and the router over all of them
+                ffn = self.n_experts_held * 3 * d * f + d * self.n_experts
                 if self.moe_dense_residual:
                     ffn += 3 * d * (self.dense_residual_ff or f)
             else:
@@ -120,20 +170,30 @@ class ModelConfig:
         return n
 
     def active_param_count(self) -> int:
-        """Activated params per token (MoE: top_k of n_experts)."""
+        """Activated params per token (MoE: the top_k of n_experts routed
+        picks, of which a held share lands here on average)."""
         if not self.is_moe:
             return self.param_count()
         d, f = self.d_model, self.d_ff
         total = self.param_count()
-        inactive = self.n_layers * (self.n_experts - self.top_k) * 3 * d * f
+        held = self.n_experts_held
+        active = self.top_k * held // self.n_experts
+        inactive = self.n_layers * (held - active) * 3 * d * f
         return total - inactive
 
     def reduce(self) -> "ModelConfig":
-        """Reduced same-family config for CPU smoke tests."""
+        """Reduced same-family config for CPU smoke tests.  Per-layer
+        attention kinds keep their first four layers (a whole 3:1 period)."""
+        n_layers = min(self.n_layers,
+                       4 if (self.attention_every or self.layer_types) else 2)
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            n_layers=min(self.n_layers, 2 if not self.attention_every else 4),
+            n_layers=n_layers,
+            layer_types=self.layer_types[:n_layers],
+            layer_window=min(self.layer_window, 16),
+            experts_held=0,
+            expert_offset=0,
             d_model=128,
             d_ff=256,
             vocab_size=512,

@@ -102,12 +102,14 @@ def mixed_attention_ref(
     v_cache: jax.Array,
     cache_lens: jax.Array, # (B,) int32 tokens already cached BEFORE this chunk
     *,
+    window=0,              # keys in (pos - window, pos] (0: all; may be traced)
     softmax_scale=None,
 ) -> jax.Array:
     """Chunked-prefill attention: query i of sequence b sits at absolute
     position ``cache_lens[b] + i`` and attends keys at positions
     ``<= cache_lens[b] + i`` (cached prefix + the chunk's earlier writes,
-    which the caller has already scattered into the cache)."""
+    which the caller has already scattered into the cache), and with a
+    ``window`` only those after ``cache_lens[b] + i - window``."""
     B, S, Hkv, D = k_cache.shape
     Q, Hq = q.shape[1], q.shape[2]
     G = Hq // Hkv
@@ -118,6 +120,8 @@ def mixed_attention_ref(
     keypos = jnp.arange(S)
     qpos = cache_lens[:, None] + jnp.arange(Q)[None, :]          # (B, Q)
     valid = keypos[None, None, :] <= qpos[:, :, None]            # (B, Q, S)
+    if not isinstance(window, int) or window > 0:
+        valid = valid & (keypos[None, None, :] > qpos[:, :, None] - window)
     s = jnp.where(valid[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v_cache.dtype), v_cache,

@@ -6,6 +6,10 @@ Cache layouts
 * sliding-window (mixtral): ring buffer of shape (B, W, Hkv, Dh) — bounds
   long_500k cache memory to the window (keys stored with absolute RoPE, so
   relative phases stay correct as the ring wraps).
+* per-layer kinds (mellum): every layer keeps a full-length cache (either
+  layout); a ``kind`` — the layer's window and RoPE tables, scanned beside
+  its weights (``transformer.layer_kinds``) — masks keys outside the window
+  (query at p sees keys in (p - W, p]) and picks the layer's RoPE.
 * paged: a shared (P, page_size, Hkv, Dh) page pool read/written through a
   (B, n_blocks) ``page_table`` — logical position ``t`` of slot ``b`` lives
   at row ``t % page_size`` of page ``page_table[b, t // page_size]``.
@@ -79,14 +83,33 @@ def fuse_qkv_weights(p) -> jax.Array:
     return jnp.concatenate([p["wq"], p["wk"], p["wv"]], axis=-1)
 
 
+def _rope(x, positions, cfg: ModelConfig, kind: Optional[Dict]):
+    if kind is None:
+        return layers.apply_rope(x, positions, cfg.rope_theta)
+    return layers.apply_rope(x, positions, cfg.rope_theta,
+                             inv_freq=kind["inv_freq"], scale=kind["rope_scale"])
+
+
+def _window(kind: Optional[Dict]):
+    """The layer's window for the masks (0: none)."""
+    return 0 if kind is None else kind["window"]
+
+
+def _no_kernel_kinds(cfg: ModelConfig, kind: Optional[Dict]) -> None:
+    if kind is not None and cfg.use_pallas:
+        raise ValueError(f"{cfg.name}: per-layer windows and RoPE kinds run on "
+                         "the XLA attention path (use_pallas=False)")
+
+
 def _project_qkv(p, x, cfg: ModelConfig, positions, *, fused: bool = False,
-                 wqkv: Optional[jax.Array] = None):
+                 wqkv: Optional[jax.Array] = None, kind: Optional[Dict] = None):
     """QKV projection.  ``fused=True`` (decode hot path) runs the three
     projections as ONE matmul — bitwise identical per output column, but a
     third of the matmul dispatches.  Pass a precomputed ``wqkv``
     (``fuse_qkv_weights``) when calling from inside a scanned layer loop;
     otherwise the concat happens here (fine when ``p`` is loop-invariant,
-    e.g. zamba2's single shared attention block)."""
+    e.g. zamba2's single shared attention block).  ``kind`` gives the
+    layer's own RoPE tables (``transformer.layer_kinds``)."""
     B = x.shape[0]
     S = x.shape[1]
     hd = cfg.resolved_head_dim
@@ -109,7 +132,7 @@ def _project_qkv(p, x, cfg: ModelConfig, positions, *, fused: bool = False,
                 jnp.broadcast_to(p["k_norm"], (Hkv, hd)),
             ])
             qk = layers.rms_norm(qk, wqk, cfg.norm_eps)
-        qk = layers.apply_rope(qk, positions, cfg.rope_theta)
+        qk = _rope(qk, positions, cfg, kind)
         q, k = qk[:, :, :Hq], qk[:, :, Hq:]
         return q, k, v
     q = jnp.einsum("bsd,dq->bsq", x, p["wq"]).reshape(B, S, Hq, hd)
@@ -118,8 +141,8 @@ def _project_qkv(p, x, cfg: ModelConfig, positions, *, fused: bool = False,
     if cfg.qk_norm:
         q = layers.rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = layers.rms_norm(k, p["k_norm"], cfg.norm_eps)
-    q = layers.apply_rope(q, positions, cfg.rope_theta)
-    k = layers.apply_rope(k, positions, cfg.rope_theta)
+    q = _rope(q, positions, cfg, kind)
+    k = _rope(k, positions, cfg, kind)
     return q, k, v
 
 
@@ -192,12 +215,15 @@ def attention_block(
     positions: Optional[jax.Array] = None,
     return_cache: bool = False,
     mesh=None,
+    kind: Optional[Dict] = None,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
-    """Prefill / training attention (chunked flash path)."""
+    """Prefill / training attention (chunked flash path).  ``kind``: the
+    layer's window and RoPE (``transformer.layer_kinds``)."""
+    _no_kernel_kinds(cfg, kind)
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    q, k, v = _project_qkv(p, x, cfg, positions)
+    q, k, v = _project_qkv(p, x, cfg, positions, kind=kind)
     cache = None
     if return_cache:
         kc, vc = k, v
@@ -231,7 +257,7 @@ def attention_block(
         out = layers.chunked_attention(
             qe, ke, ve,
             causal=cfg.causal,
-            window=cfg.sliding_window,
+            window=cfg.sliding_window if kind is None else kind["window"],
             q_chunk=min(1024, S),
             k_chunk=min(1024, S),
         )
@@ -249,6 +275,7 @@ def attention_decode(
     cfg: ModelConfig,
     wqkv: Optional[jax.Array] = None,   # precomputed fuse_qkv_weights(p)
     page_table: Optional[jax.Array] = None,   # (B, n_blocks) int32 page ids
+    kind: Optional[Dict] = None,        # the layer's window and RoPE
 ) -> Tuple[jax.Array, KVCache]:
     """One decode step of layer ``layer``: write the token's KV into the
     stacked cache (ring for SWA), attend over that layer, project.
@@ -264,12 +291,14 @@ def attention_decode(
     ps, Hkv, Dh): the new token's KV scatters to its table-resolved (page,
     row) and attention reads through the table — the Pallas paged kernel
     gathers pages inside its grid; the lax fallback gathers then reuses the
-    dense reference.  Paged mode requires ragged (B,) ``cache_len`` and full
-    (non-sliding-window) attention.
+    dense reference.  Paged mode requires ragged (B,) ``cache_len`` and no
+    ring window; per-layer windows (``kind``) mask either layout.
     """
+    _no_kernel_kinds(cfg, kind)
     if page_table is not None:
         return _attention_decode_paged(p, x, cache, layer, cache_len, cfg,
-                                       wqkv=wqkv, page_table=page_table)
+                                       wqkv=wqkv, page_table=page_table,
+                                       kind=kind)
     B = x.shape[0]
     cache_len = jnp.asarray(cache_len, jnp.int32)
     ragged = cache_len.ndim == 1
@@ -277,7 +306,8 @@ def attention_decode(
         cache_len[:, None] if ragged
         else jnp.broadcast_to(cache_len, (B, 1)).astype(jnp.int32)
     )
-    q, k_new, v_new = _project_qkv(p, x, cfg, positions, fused=True, wqkv=wqkv)
+    q, k_new, v_new = _project_qkv(p, x, cfg, positions, fused=True, wqkv=wqkv,
+                                   kind=kind)
 
     W = cache.k.shape[2]
     if cfg.sliding_window > 0:
@@ -306,7 +336,8 @@ def attention_decode(
         lengths = eff_len if ragged else jnp.broadcast_to(eff_len, (B,))
         out = kdecode(q[:, 0], k_l, v_l, lengths, block_k=math.gcd(W, 512))
     else:
-        out = layers.decode_attention(q[:, 0], k_l, v_l, eff_len, window=0)
+        out = layers.decode_attention(q[:, 0], k_l, v_l, eff_len,
+                                      window=_window(kind))
     out = jnp.einsum("bq,qd->bd", out.reshape(B, cfg.q_dim), p["wo"])[:, None, :]
     return out, KVCache(k=k_c, v=v_c)
 
@@ -321,6 +352,7 @@ def _attention_decode_paged(
     *,
     wqkv: Optional[jax.Array],
     page_table: jax.Array,              # (B, n_blocks) int32
+    kind: Optional[Dict] = None,
 ) -> Tuple[jax.Array, KVCache]:
     if cfg.sliding_window > 0:
         raise ValueError("paged KV does not support sliding-window attention")
@@ -330,7 +362,8 @@ def _attention_decode_paged(
     if cache_len.ndim != 1:
         raise ValueError("paged decode requires (B,) per-slot cache_len")
     positions = cache_len[:, None]
-    q, k_new, v_new = _project_qkv(p, x, cfg, positions, fused=True, wqkv=wqkv)
+    q, k_new, v_new = _project_qkv(p, x, cfg, positions, fused=True, wqkv=wqkv,
+                                   kind=kind)
 
     # scatter the new token's KV to its (page, row).  Idle/finished slots
     # resolve to the trash page; colliding trash writes are harmless.
@@ -351,7 +384,7 @@ def _attention_decode_paged(
     else:
         out = layers.decode_attention(
             q[:, 0], _layer_pages(k_c, layer, page_table),
-            _layer_pages(v_c, layer, page_table), eff_len, window=0,
+            _layer_pages(v_c, layer, page_table), eff_len, window=_window(kind),
         )
     out = jnp.einsum("bq,qd->bd", out.reshape(B, cfg.q_dim), p["wo"])[:, None, :]
     return out, KVCache(k=k_c, v=v_c)
@@ -369,6 +402,7 @@ def attention_mixed(
     wqkv: Optional[jax.Array] = None,   # precomputed fuse_qkv_weights(p)
     page_table: Optional[jax.Array] = None,   # (B, n_blocks) => paged pool
     attn_window: Optional[int] = None,  # static: keys [0, attn_window) suffice
+    kind: Optional[Dict] = None,        # the layer's window and RoPE
 ) -> Tuple[jax.Array, KVCache]:
     """One mixed-batch step of layer ``layer``: every slot advances by its
     own ragged suffix.
@@ -393,9 +427,13 @@ def attention_mixed(
     not depend on it: the causal mask already excludes everything past the
     content frontier.
 
-    Requires full attention (no sliding window) and ragged (B,) lengths —
-    the same contract as the paged decode path.
+    A layer with a window of its own (``kind``) also masks keys at or
+    before ``position - window``; its cache still holds every position.
+
+    Requires ragged (B,) lengths and no ring window (``cfg.sliding_window``)
+    — the same contract as the paged decode path.
     """
+    _no_kernel_kinds(cfg, kind)
     if cfg.sliding_window > 0:
         raise ValueError("mixed-batch steps do not support sliding-window attention")
     B, Q, _ = x.shape
@@ -404,7 +442,8 @@ def attention_mixed(
     if cache_lens.ndim != 1:
         raise ValueError("mixed-batch steps require (B,) per-slot cache_lens")
     positions = cache_lens[:, None] + jnp.arange(Q, dtype=jnp.int32)[None, :]
-    q, k_new, v_new = _project_qkv(p, x, cfg, positions, fused=True, wqkv=wqkv)
+    q, k_new, v_new = _project_qkv(p, x, cfg, positions, fused=True, wqkv=wqkv,
+                                   kind=kind)
     valid = jnp.arange(Q, dtype=jnp.int32)[None, :] < new_lens[:, None]
 
     if page_table is not None:
@@ -446,7 +485,7 @@ def attention_mixed(
     else:
         from repro.kernels.decode_attention.ref import mixed_attention_ref
 
-        out = mixed_attention_ref(q, k_r, v_r, cache_lens)
+        out = mixed_attention_ref(q, k_r, v_r, cache_lens, window=_window(kind))
     out = jnp.einsum("bqk,kd->bqd", out.reshape(B, Q, cfg.q_dim), p["wo"])
     return out, KVCache(k=k_c, v=v_c)
 
@@ -459,6 +498,7 @@ def attention_prefill_paged(
     page_row: jax.Array,                # (nb,) int32: ONE slot's block table
     start: jax.Array,                   # scalar int32: tokens already cached
     cfg: ModelConfig,
+    kind: Optional[Dict] = None,        # the layer's window and RoPE
 ) -> Tuple[jax.Array, KVCache]:
     """Continuation prefill of layer ``layer``: extend a paged cache by T
     tokens in ONE step.
@@ -479,7 +519,7 @@ def attention_prefill_paged(
     G = Hq // Hkv
     ps = pool.k.shape[2]
     pos = start + jnp.arange(T, dtype=jnp.int32)        # (T,) absolute
-    q, k_new, v_new = _project_qkv(p, x, cfg, pos[None, :])
+    q, k_new, v_new = _project_qkv(p, x, cfg, pos[None, :], kind=kind)
     pages = page_row[pos // ps]
     rows = pos % ps
     with jax.named_scope("kv_write"):
@@ -494,6 +534,8 @@ def attention_prefill_paged(
                    preferred_element_type=jnp.float32) * scale
     keypos = jnp.arange(kg.shape[0])
     mask = keypos[None, :] <= pos[:, None]              # causal continuation
+    if kind is not None:
+        mask = mask & (keypos[None, :] > pos[:, None] - kind["window"])
     s = jnp.where(mask[None, None], s, -1e30)
     pr = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("hgts,shd->thgd", pr.astype(vg.dtype), vg,

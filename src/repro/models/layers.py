@@ -17,6 +17,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 # ---------------------------------------------------------------------------
@@ -54,13 +55,41 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, Dh); positions: (..., S) int32."""
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original_max: float, beta_fast: float,
+                     beta_slow: float) -> np.ndarray:
+    """YaRN's inverse frequencies (Hugging Face ``_compute_yarn_parameters``,
+    truncated correction range): theta^(-2i/d) kept where a dimension turns
+    more than ``beta_fast`` times over ``original_max`` positions, divided by
+    ``factor`` where it turns fewer than ``beta_slow`` times, and blended by
+    a linear ramp between the two correction dimensions."""
+    def corr_dim(rotations):
+        return (head_dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    base = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim)
+    return (base * (1.0 - ramp) + base / factor * ramp).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               inv_freq: Optional[jax.Array] = None,
+               scale: Optional[jax.Array] = None) -> jax.Array:
+    """x: (..., S, H, Dh); positions: (..., S) int32.  ``inv_freq`` (Dh/2,)
+    replaces theta's frequencies and ``scale`` multiplies cos and sin (a
+    layer's own RoPE kind, e.g. YaRN's attention factor)."""
     dh = x.shape[-1]
-    freqs = rope_frequencies(dh, theta)                       # (Dh/2,)
+    freqs = rope_frequencies(dh, theta) if inv_freq is None else inv_freq
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, Dh/2)
     cos = jnp.cos(angles)[..., None, :]                        # (..., S, 1, Dh/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -73,14 +102,19 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 NEG_INF = -1e30
 
 
-def _block_mask(q_pos: jax.Array, k_pos: jax.Array, causal: bool, window: int) -> jax.Array:
+def has_window(window) -> bool:
+    """A window to mask by: a positive int, or a traced per-layer value."""
+    return not isinstance(window, int) or window > 0
+
+
+def _block_mask(q_pos: jax.Array, k_pos: jax.Array, causal: bool, window) -> jax.Array:
     """(Cq, Ck) bool mask: True = attend."""
     dq = q_pos[:, None]
     dk = k_pos[None, :]
     m = jnp.ones((q_pos.shape[0], k_pos.shape[0]), dtype=bool)
     if causal:
         m = m & (dk <= dq)
-    if window > 0:
+    if has_window(window):
         m = m & (dk > dq - window)
     return m
 
@@ -91,7 +125,7 @@ def chunked_attention(
     v: jax.Array,                 # (B, Sk, H, Dh)
     *,
     causal: bool = True,
-    window: int = 0,              # sliding window (0 = unbounded)
+    window=0,                     # sliding window (0 = unbounded; may be traced)
     q_offset: int = 0,            # absolute position of q[0] (prefill w/ cache)
     q_chunk: int = 1024,
     k_chunk: int = 1024,
@@ -168,7 +202,7 @@ def decode_attention(
     v_cache: jax.Array,           # (B, S, Hkv, Dh)
     cache_len: jax.Array,         # scalar or (B,) — valid prefix length
     *,
-    window: int = 0,
+    window=0,                     # keys in (len - 1 - window, len - 1]; may be traced
     softmax_scale: Optional[float] = None,
 ) -> jax.Array:
     """Single-token attention against a (possibly sharded) KV cache.
@@ -191,8 +225,8 @@ def decode_attention(
         valid = pos[None, :] < cache_len
     else:
         valid = pos[None, :] < cache_len[:, None]
-    if window > 0:
-        lo = (cache_len if jnp.ndim(cache_len) else cache_len) - window
+    if has_window(window):
+        lo = cache_len - window
         valid = valid & (pos[None, :] >= jnp.asarray(lo).reshape(-1, 1))
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)

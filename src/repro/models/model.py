@@ -100,7 +100,7 @@ class Model:
         return logits, cache
 
     def decode(self, params, tokens, cache, cache_len, fused=None,
-               page_table=None):
+               page_table=None, count_experts=False):
         """tokens: (B,1) i32; cache_len: scalar i32 (tokens already cached)
         or (B,) per-slot lengths (continuous batching).
 
@@ -112,9 +112,12 @@ class Model:
         paged layout (``empty_page_pool``): each slot reads/writes the
         shared page pool through its table row (transformer families only).
 
-        Returns (logits (B,V), new_cache)."""
+        Returns (logits (B,V), new_cache), and with ``count_experts`` also
+        the (token, held expert) rows the expert layers computed (None for
+        a model without experts)."""
         cfg = self.cfg
         x = jnp.take(params["embed"], tokens, axis=0)
+        rows = None
         if cfg.family == "rwkv":
             if page_table is not None:
                 raise ValueError("paged KV is not supported for rwkv caches")
@@ -129,22 +132,24 @@ class Model:
             if fused is None:
                 fused = transformer.fused_decode_weights(params, cfg)
 
-            def attn(p, h, kv, layer, wqkv):
+            def attn(p, h, kv, layer, wqkv, kind):
                 return attention.attention_decode(
                     p, h, kv, layer, cache_len, cfg, wqkv=wqkv,
-                    page_table=page_table)
+                    page_table=page_table, kind=kind)
 
-            x, nk, nv = transformer.run_layers_kv(
+            x, nk, nv, rows = transformer.run_layers_kv(
                 params, x, cache.k, cache.v, attn, cfg, self.mesh,
                 fused=fused,
             )
             new_cache = DecoderKVCache(k=nk, v=nv)
         logits = transformer.logits_from_hidden(params, x, cfg, self.mesh)[:, 0]
+        if count_experts:
+            return logits, new_cache, rows
         return logits, new_cache
 
     def step_mixed(self, params, tokens, cache, cache_lens, new_lens,
                    fused=None, page_table=None, attn_window=None,
-                   all_logits=False):
+                   all_logits=False, count_experts=False):
         """One mixed-batch engine step: each slot advances by its own
         ragged suffix ``tokens[b, :new_lens[b]]`` starting at cache
         position ``cache_lens[b]`` — decode steps (new_len 1) and prefill
@@ -162,9 +167,11 @@ class Model:
         decode verification needs (each draft column checked against the
         distribution its prefix induces, all in this one dispatch).
 
-        Transformer families with full attention only (the paged-KV
+        ``count_experts`` adds a third output, as in ``decode``.
+
+        Transformer families without a ring window only (the paged-KV
         constraint): SSM/RWKV decode state cannot replay multi-token
-        suffixes in one step."""
+        suffixes in one step.  Per-layer windows mask inside the step."""
         cfg = self.cfg
         if not self.supports_mixed_step:
             raise ValueError(f"{cfg.name}: mixed-batch step unsupported "
@@ -174,28 +181,33 @@ class Model:
         if fused is None:
             fused = transformer.fused_decode_weights(params, cfg)
 
-        def attn(p, h, kv, layer, wqkv):
+        def attn(p, h, kv, layer, wqkv, kind):
             return attention.attention_mixed(
                 p, h, kv, layer, cache_lens, new_lens, cfg, wqkv=wqkv,
-                page_table=page_table, attn_window=attn_window)
+                page_table=page_table, attn_window=attn_window, kind=kind)
 
-        x, nk, nv = transformer.run_layers_kv(
+        x, nk, nv, rows = transformer.run_layers_kv(
             params, x, cache.k, cache.v, attn, cfg, self.mesh,
             fused=fused,
         )
         if all_logits:
             logits = transformer.logits_from_hidden(params, x, cfg, self.mesh)
-            return logits, DecoderKVCache(k=nk, v=nv)
-        last = jnp.maximum(jnp.asarray(new_lens, jnp.int32) - 1, 0)
-        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
-        logits = transformer.logits_from_hidden(params, x_last, cfg, self.mesh)[:, 0]
+        else:
+            last = jnp.maximum(jnp.asarray(new_lens, jnp.int32) - 1, 0)
+            x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
+            logits = transformer.logits_from_hidden(params, x_last, cfg,
+                                                    self.mesh)[:, 0]
+        if count_experts:
+            return logits, DecoderKVCache(k=nk, v=nv), rows
         return logits, DecoderKVCache(k=nk, v=nv)
 
     @property
     def supports_mixed_step(self) -> bool:
         """Mixed-batch chunked prefill shares the paged-KV structural
         contract: a (L, ..., S, Hkv, Dh) KV cache whose positions can be
-        written out of lockstep, and full (non-ring) attention."""
+        written out of lockstep, so no ring window (``sliding_window``).
+        Windows of single layers (``layer_types``) keep full-length caches
+        and are masked inside the step, so they run here."""
         cfg = self.cfg
         return (cfg.supports_decode
                 and cfg.family not in ("rwkv", "hybrid")
@@ -245,11 +257,11 @@ class Model:
             raise ValueError(f"{cfg.name}: paged prefill unsupported")
         x = jnp.take(params["embed"], tokens, axis=0)
 
-        def attn(p, h, kv, layer, wqkv):
+        def attn(p, h, kv, layer, wqkv, kind):
             return attention.attention_prefill_paged(
-                p, h, kv, layer, page_row, start, cfg)
+                p, h, kv, layer, page_row, start, cfg, kind=kind)
 
-        x, nk, nv = transformer.run_layers_kv(
+        x, nk, nv, _ = transformer.run_layers_kv(
             params, x, pool.k, pool.v, attn, cfg, self.mesh)
         logits = transformer.logits_from_hidden(
             params, x[:, -1:], cfg, self.mesh
@@ -259,8 +271,10 @@ class Model:
     @property
     def supports_paged_kv(self) -> bool:
         """Paged KV needs the (L, ..., S, Hkv, Dh) DecoderKVCache layout and
-        full (non-ring) attention; SSM/RWKV state caches have no pages to
-        share and the SWA ring already bounds its own memory."""
+        no ring window; SSM/RWKV state caches have no pages to share and
+        the SWA ring already bounds its own memory.  Windows of single
+        layers page like full layers: every layer keeps every position's
+        page (releasing pages that leave a window is not done yet)."""
         cfg = self.cfg
         return (cfg.supports_decode
                 and cfg.family not in ("rwkv", "hybrid")

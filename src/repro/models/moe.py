@@ -1,6 +1,21 @@
-"""Mixture-of-Experts block: top-k routing + capacity-bucketed dispatch.
+"""Mixture-of-Experts block: top-k routing over every expert, then the
+experts this model holds.
 
-Two sharding regimes, selected by expert-count divisibility (DESIGN.md §5):
+Without a mesh (serving, and every single-device forward) the layer is
+dropless (``_held_experts``): the router's softmax spans all ``n_experts``,
+each token keeps its ``top_k`` picks with their gates renormalised, and
+every pick that lands on a held expert (ids ``[expert_offset,
+expert_offset + n_experts_held)``) is computed, through grouped matmuls
+over the picks sorted by expert.  Picks on experts held elsewhere add
+nothing here: on one chip of an expert-parallel deployment this is the
+chip's share of the layer, and the exchange that would sum the shares is
+not part of this program.  No pick is ever dropped, so a token's output
+does not depend on its batchmates.
+
+The capacity-bucketed dispatch below (``_dispatch``, ``_combine``,
+``_capacity``) serves only the sharded training path, ``_moe_sharded``,
+in two sharding regimes selected by expert-count divisibility
+(DESIGN.md §5):
 
 * **EP** (arctic: 128 experts % 16 == 0): expert weights sharded over the
   ``model`` axis.  Activations arriving at the block are replicated over
@@ -14,9 +29,9 @@ Two sharding regimes, selected by expert-count divisibility (DESIGN.md §5):
   with the FFN dim sliced over ``model``; dispatch is local, combine is the
   usual TP psum.
 
-Dispatch itself is a capacity-bucketed scatter: O(E·C·d) memory, never the
-(T, E, C) one-hot tensor.  Tokens overflowing an expert's capacity fall
-through to the residual path (standard Switch/GShard semantics).
+There, dispatch is a capacity-bucketed scatter: O(E·C·d) memory, never
+the (T, E, C) one-hot tensor.  Tokens overflowing an expert's capacity
+fall through to the residual path (standard Switch/GShard semantics).
 """
 from __future__ import annotations
 
@@ -32,10 +47,12 @@ from repro.models import layers
 
 
 def init_moe_params(key: jax.Array, cfg: ModelConfig, dtype) -> Dict[str, jax.Array]:
-    d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router over all ``n_experts``; the weights of the held ones."""
+    d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts_held
     ks = jax.random.split(key, 5)
     p = {
-        "router": layers.dense_init(ks[0], (d, E), jnp.float32, scale=0.02),
+        "router": layers.dense_init(ks[0], (d, cfg.n_experts), jnp.float32,
+                                    scale=0.02),
         "w_gate": layers.dense_init(ks[1], (E, d, f), dtype),
         "w_up": layers.dense_init(ks[2], (E, d, f), dtype),
         "w_down": layers.dense_init(ks[3], (E, f, d), dtype),
@@ -131,26 +148,54 @@ def _dispatch_compute_combine(
     return _combine(y, slot, token_idx, cw, T)
 
 
+def _held_experts(p, x: jax.Array, cfg: ModelConfig):
+    """The dropless expert layer on (T, d) tokens: (out (T, d), aux, rows).
+
+    Route over all ``n_experts``; sort the (token, pick) pairs by held
+    expert, the picks on experts not held here last and outside every
+    group; run SwiGLU as three grouped matmuls (``lax.ragged_dot``) over
+    the groups; put the rows back in (token, pick) order and sum each
+    token's picks times their gates, in float32, in pick order.  ``rows``
+    is the number of (token, held expert) rows the matmuls computed."""
+    T, d = x.shape
+    k = cfg.top_k
+    e_loc = p["w_gate"].shape[0]
+    gates, experts, aux = _route(x, p["router"], k)
+    local = experts.reshape(-1) - cfg.expert_offset                # (T*k,)
+    held = (local >= 0) & (local < e_loc)
+    group = jnp.where(held, local, e_loc)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=e_loc + 1)[:e_loc].astype(jnp.int32)
+    xs = jnp.take(x, order // k, axis=0)                         # (T*k, d)
+    g = lax.ragged_dot(xs, p["w_gate"], sizes)
+    u = lax.ragged_dot(xs, p["w_up"], sizes)
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+    y = lax.ragged_dot(h, p["w_down"], sizes)   # rows past the groups: undefined
+    y = jnp.zeros_like(y).at[order].set(y, unique_indices=True)
+    w = jnp.where(held, gates.reshape(-1), 0.0)
+    y = jnp.where(held[:, None], y.astype(jnp.float32), 0.0) * w[:, None]
+    out = jnp.sum(y.reshape(T, k, d), axis=1).astype(x.dtype)
+    return out, aux, jnp.sum(sizes)
+
+
 def moe_block(
     p: Dict[str, jax.Array],
     x: jax.Array,             # (B, S, d)
     cfg: ModelConfig,
     mesh: Optional[jax.sharding.Mesh] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Returns (out (B,S,d), aux_loss scalar).
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """Returns (out (B,S,d), aux_loss scalar, rows).
 
-    With a mesh: shard_map over (pod, data, model); without (CPU smoke
-    tests): single-shard fast path.
+    Without a mesh: the dropless layer of the held experts, and ``rows``
+    the (token, held expert) rows it computed.  With a mesh: shard_map over
+    (pod, data, model), capacity-bucketed, and ``rows`` None.
     """
     B, S, d = x.shape
     xf = x.reshape(B * S, d)
 
+    rows = None
     if mesh is None or "model" not in mesh.axis_names:
-        gates, experts, aux = _route(xf, p["router"], cfg.top_k)
-        cap = _capacity(B * S, cfg)
-        out = _dispatch_compute_combine(
-            xf, gates, experts, p["w_gate"], p["w_up"], p["w_down"], 0, cap
-        )
+        out, aux, rows = _held_experts(p, xf, cfg)
         out = out.reshape(B, S, d)
     else:
         out, aux = _moe_sharded(p, xf, cfg, mesh)
@@ -158,7 +203,7 @@ def moe_block(
 
     if cfg.moe_dense_residual:
         out = out + layers.swiglu(x, p["res_gate"], p["res_up"], p["res_down"])
-    return out, aux
+    return out, aux, rows
 
 
 def _capacity(tokens: int, cfg: ModelConfig) -> int:
@@ -174,6 +219,10 @@ def _moe_sharded(p, xf, cfg: ModelConfig, mesh) -> Tuple[jax.Array, jax.Array]:
     explicit analogue of XLA's FSDP weight gathering.  The only other
     collective is the combine psum over 'model'.
     """
+    if cfg.n_experts_held != cfg.n_experts:
+        raise ValueError(f"{cfg.name}: the sharded path shards all "
+                         f"{cfg.n_experts} experts; this model holds "
+                         f"{cfg.n_experts_held}")
     axis_names = mesh.axis_names                     # ("pod","data","model") or ("data","model")
     batch_axes = tuple(a for a in axis_names if a != "model")
     model_size = mesh.shape["model"]

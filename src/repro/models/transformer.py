@@ -13,6 +13,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.configs.base import ModelConfig
@@ -126,13 +127,46 @@ def full_activation(x: jax.Array, mesh) -> jax.Array:
     )
 
 
+# a full layer's "window": wider than any cache, so its mask drops nothing
+NO_WINDOW = 1 << 30
+
+
+def layer_kinds(cfg: ModelConfig) -> Optional[Dict[str, jax.Array]]:
+    """Each layer's attention kind, stacked on a leading layer axis to scan
+    beside the weights: ``window`` (L,) int32 (``NO_WINDOW`` for a full
+    layer), ``inv_freq`` (L, head_dim/2) and ``rope_scale`` (L,) float32 —
+    default RoPE for windowed layers, the configured YaRN for full ones.
+    None where every layer attends alike, so such a model's layer loop
+    carries nothing new."""
+    if not cfg.has_layer_kinds:
+        return None
+    hd = cfg.resolved_head_dim
+    default = (1.0 / cfg.rope_theta ** (np.arange(0, hd, 2, dtype=np.float32)
+                                        / hd)).astype(np.float32)
+    full_freq, full_scale = default, 1.0
+    if cfg.rope_yarn:
+        factor, orig, beta_fast, beta_slow, attn_factor = cfg.rope_yarn
+        full_freq = layers.yarn_frequencies(hd, cfg.rope_theta, factor, orig,
+                                            beta_fast, beta_slow)
+        full_scale = attn_factor
+    windows = cfg.layer_windows
+    return {
+        "window": jnp.asarray([w or NO_WINDOW for w in windows], jnp.int32),
+        "inv_freq": jnp.asarray(np.stack([default if w else full_freq
+                                          for w in windows])),
+        "rope_scale": jnp.asarray([1.0 if w else full_scale for w in windows],
+                                  jnp.float32),
+    }
+
+
 def mlp_block(lp, h, cfg: ModelConfig, mesh):
     """Post-attention feed-forward dispatch (MoE / gelu / swiglu) shared by
     the sequence and cached-step layer bodies.  Returns (m, aux).  A cached
     step with fused swiglu weights consumes the pre-fused [w_gate|w_up]
     matrix instead (``run_layers_kv``)."""
     if cfg.is_moe:
-        return moe.moe_block(lp["moe"], h, cfg, mesh)
+        m, aux, _ = moe.moe_block(lp["moe"], h, cfg, mesh)
+        return m, aux
     if cfg.mlp_type == "gelu":
         hu = jnp.einsum("...d,df->...f", h, lp["mlp"]["w_up"])
         hu = jax.nn.gelu(hu.astype(jnp.float32)).astype(h.dtype)
@@ -143,7 +177,7 @@ def mlp_block(lp, h, cfg: ModelConfig, mesh):
     return m, jnp.zeros((), jnp.float32)
 
 
-def _layer_seq(lp, x, cfg: ModelConfig, mesh, return_cache: bool):
+def _layer_seq(lp, x, cfg: ModelConfig, mesh, return_cache: bool, kind=None):
     """One transformer layer on (B,S,d). Returns (x, (cache_k, cache_v), aux).
 
     With ``cfg.seq_parallel`` (a §Perf experiment), inter-block activations
@@ -157,7 +191,7 @@ def _layer_seq(lp, x, cfg: ModelConfig, mesh, return_cache: bool):
     if sp:
         h = full_activation(h, mesh)
     a, cache = attention.attention_block(
-        lp["attn"], h, cfg, return_cache=return_cache, mesh=mesh,
+        lp["attn"], h, cfg, return_cache=return_cache, mesh=mesh, kind=kind,
     )
     x = x + (seq_shard(a, mesh) if sp else a)
     h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
@@ -182,12 +216,14 @@ def run_layers_seq(
     L = cfg.n_layers
     G, Lg = factor_layers(L, cfg.scan_group)
     grouped = jax.tree.map(
-        lambda a: a.reshape(G, Lg, *a.shape[1:]), params["layers"]
+        lambda a: a.reshape(G, Lg, *a.shape[1:]),
+        (params["layers"], layer_kinds(cfg)),
     )
 
-    def layer_body(carry, lp):
+    def layer_body(carry, inputs):
         x, aux = carry
-        x, cache, a = _layer_seq(lp, x, cfg, mesh, return_cache)
+        lp, kind = inputs
+        x, cache, a = _layer_seq(lp, x, cfg, mesh, return_cache, kind)
         return (x, aux + a), cache
 
     def group_body(carry, gp):
@@ -238,7 +274,7 @@ def run_layers_kv(
     x: jax.Array,                # (B, T, d): the new tokens' embeddings
     cache_k: jax.Array,          # (L, B, Sc, Hkv, Dh) or paged (L, P, ps, Hkv, Dh)
     cache_v: jax.Array,
-    attn: Callable,              # attn(p, h, KVCache, layer, wqkv) -> (a, KVCache)
+    attn: Callable,              # attn(p, h, KVCache, layer, wqkv, kind) -> (a, KVCache)
     cfg: ModelConfig,
     mesh=None,
     fused: Optional[Dict] = None,   # fused_decode_weights(params, cfg)
@@ -256,34 +292,46 @@ def run_layers_kv(
 
     ``fused`` selects the fused QKV and gate/up projections of the decode
     hot path; without it a layer runs the plain ``mlp_block`` (the
-    continuation prefill).  Returns (x, new_k, new_v)."""
+    continuation prefill).  Where layers differ in window or RoPE, each
+    layer's kind (``layer_kinds``) rides the scan beside its weights and
+    reaches ``attn``; otherwise ``attn`` gets None.
+
+    Returns (x, new_k, new_v, expert_rows): the (token, held expert) rows
+    the expert layers computed, summed over the layers — None for a model
+    without experts."""
+    count = cfg.is_moe and mesh is None
 
     def body(carry, inputs):
-        x, k, v = carry
-        lp, i, w = inputs
+        x, k, v, rows = carry
+        lp, i, w, kind = inputs
         h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
         with jax.named_scope("attn"):
             a, kv = attn(lp["attn"], h, attention.KVCache(k=k, v=v), i,
-                         None if w is None else w["wqkv"])
+                         None if w is None else w["wqkv"], kind)
         x = x + a
         h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
         with jax.named_scope("mlp"):
-            if w is None or w["w_gu"] is None:
+            if count:
+                m, _, r = moe.moe_block(lp["moe"], h, cfg, mesh)
+                rows = rows + r
+            elif w is None or w["w_gu"] is None:
                 m, _ = mlp_block(lp, h, cfg, mesh)
             else:
                 m = layers.swiglu_fused(h, w["w_gu"], lp["mlp"]["w_down"])
         x = x + m
-        return (x, kv.k, kv.v), None
+        return (x, kv.k, kv.v, rows), None
 
     # small unroll: decode bodies are tiny, so the layer loop's while
     # overhead is material on CPU/small models; 4 keeps HLO size bounded
     L = cfg.n_layers
-    (x, k, v), _ = lax.scan(
-        body, (x, cache_k, cache_v),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32), fused),
+    rows0 = jnp.zeros((), jnp.int32) if count else None
+    (x, k, v, rows), _ = lax.scan(
+        body, (x, cache_k, cache_v, rows0),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32), fused,
+         layer_kinds(cfg)),
         unroll=min(4, L),
     )
-    return x, k, v
+    return x, k, v, rows
 
 
 # ---------------------------------------------------------------------------

@@ -159,6 +159,10 @@ class EngineTelemetry:
     drafted_tokens: int = 0          # draft tokens dispatched for verification
     accepted_tokens: int = 0         # drafts that survived verification
     spec_rounds: int = 0             # fused verify dispatches (>=1 draft in)
+    # sparse experts (zero without them): (token, held expert) rows the
+    # steps' expert layers computed, every row of the step (padding and
+    # idle slots included), summed over layers
+    expert_rows: int = 0
 
     @property
     def tokens_per_s(self) -> float:
@@ -178,6 +182,16 @@ class EngineTelemetry:
     def cache_hit_rate(self) -> float:
         total = self.prefix_hits + self.prefix_misses
         return self.prefix_hits / total if total else 0.0
+
+
+def _add_rows(rows, r):
+    """Running expert-row count of a scan (None stays None)."""
+    return None if rows is None else rows + r
+
+
+def _rows_of(rows) -> int:
+    """A step's expert rows as read back (0 where the model has none)."""
+    return 0 if rows is None else int(rows)
 
 
 class ServingEngine:
@@ -331,20 +345,27 @@ class ServingEngine:
         return jax.tree.map(place, buf, pcache)
 
     # -- continuous batching (DecodeSlots-driven) ----------------------------
+    def _rows0(self):
+        """The expert-row count a step starts from: None without experts,
+        so a dense model's programs carry and return nothing new."""
+        return jnp.zeros((), jnp.int32) if self.model.cfg.is_moe else None
+
     def _chunk_scan(self, params, cache, tok, lens, active, key, steps: int):
         """Ragged decode chunk: every ``active`` slot advances ``steps``
         tokens with its own cache length; empty/finished/mid-prefill slots
         decode discarded garbage and their cache length stays frozen (the
         garbage KV lands at a position real writes overwrite before any
-        attention unmasks it)."""
+        attention unmasks it).  Returns (cache, tok, lens, key, toks,
+        expert_rows), the last None without experts."""
         max_row = jnp.int32(self.cfg.max_len - 1)
         greedy = self.cfg.temperature <= 0.0
         fused = self.model.fused_decode_weights(params)
 
         def step(carry, _):
-            tok, cache, lens, key = carry
-            logits, cache = self.model.decode(
-                params, tok[:, None], cache, lens, fused=fused
+            tok, cache, lens, key, rows = carry
+            logits, cache, r = self.model.decode(
+                params, tok[:, None], cache, lens, fused=fused,
+                count_experts=True,
             )
             if not greedy:
                 key, sub = jax.random.split(key)
@@ -352,13 +373,13 @@ class ServingEngine:
             else:
                 nxt = self._sample(logits, key)
             lens = jnp.where(active, jnp.minimum(lens + 1, max_row), lens)
-            return (nxt, cache, lens, key), tok
+            return (nxt, cache, lens, key, _add_rows(rows, r)), tok
 
-        (tok, cache, lens, key), toks = lax.scan(
-            step, (tok, cache, lens, key), None, length=steps,
+        (tok, cache, lens, key, rows), toks = lax.scan(
+            step, (tok, cache, lens, key, self._rows0()), None, length=steps,
             unroll=min(4, steps),
         )
-        return cache, tok, lens, key, toks        # toks: (steps, B)
+        return cache, tok, lens, key, toks, rows  # toks: (steps, B)
 
     # -- paged-KV jitted bodies ----------------------------------------------
     def _chunk_scan_paged(self, params, pool, tables, tok, lens, active, key,
@@ -370,10 +391,10 @@ class ServingEngine:
         fused = self.model.fused_decode_weights(params)
 
         def step(carry, _):
-            tok, pool, lens, key = carry
-            logits, pool = self.model.decode(
+            tok, pool, lens, key, rows = carry
+            logits, pool, r = self.model.decode(
                 params, tok[:, None], pool, lens, fused=fused,
-                page_table=tables,
+                page_table=tables, count_experts=True,
             )
             if not greedy:
                 key, sub = jax.random.split(key)
@@ -381,13 +402,13 @@ class ServingEngine:
             else:
                 nxt = self._sample(logits, key)
             lens = jnp.where(active, jnp.minimum(lens + 1, max_row), lens)
-            return (nxt, pool, lens, key), tok
+            return (nxt, pool, lens, key, _add_rows(rows, r)), tok
 
-        (tok, pool, lens, key), toks = lax.scan(
-            step, (tok, pool, lens, key), None, length=steps,
+        (tok, pool, lens, key, rows), toks = lax.scan(
+            step, (tok, pool, lens, key, self._rows0()), None, length=steps,
             unroll=min(4, steps),
         )
-        return pool, tok, lens, key, toks         # toks: (steps, B)
+        return pool, tok, lens, key, toks, rows   # toks: (steps, B)
 
     # -- mixed-batch (chunked prefill + decode) jitted bodies -----------------
     def _mixed_tokens(self, chunks, tok, is_decode):
@@ -404,26 +425,27 @@ class ServingEngine:
         ``attn_window`` (static, pow-2-bucketed by the caller) bounds the
         cache span attention reads — the content frontier, so score work
         tracks actual lengths instead of max_len.  Returns
-        (last-valid-position logits (B, V), cache, advanced lens)."""
+        (last-valid-position logits (B, V), cache, advanced lens,
+        expert_rows), the last None without experts."""
         self.mixed_traces += 1
         fused = self.model.fused_decode_weights(params)
         tokens = self._mixed_tokens(chunks, tok, is_decode)
-        logits, cache = self.model.step_mixed(
+        logits, cache, rows = self.model.step_mixed(
             params, tokens, cache, lens, new_lens, fused=fused,
-            attn_window=attn_window,
+            attn_window=attn_window, count_experts=True,
         )
-        return logits, cache, lens + new_lens
+        return logits, cache, lens + new_lens, rows
 
     def _mixed_step_paged_fn(self, params, pool, tables, chunks, tok, lens,
                              new_lens, is_decode, attn_window: int):
         self.mixed_traces += 1
         fused = self.model.fused_decode_weights(params)
         tokens = self._mixed_tokens(chunks, tok, is_decode)
-        logits, pool = self.model.step_mixed(
+        logits, pool, rows = self.model.step_mixed(
             params, tokens, pool, lens, new_lens, fused=fused,
-            page_table=tables, attn_window=attn_window,
+            page_table=tables, attn_window=attn_window, count_experts=True,
         )
-        return logits, pool, lens + new_lens
+        return logits, pool, lens + new_lens, rows
 
     # -- speculative-decode jitted bodies -------------------------------------
     def _spec_step_fn(self, params, cache, chunks, tok, lens, new_lens,
@@ -441,9 +463,9 @@ class ServingEngine:
         self.mixed_traces += 1
         fused = self.model.fused_decode_weights(params)
         tokens = self._mixed_tokens(chunks, tok, is_decode)
-        logits, cache = self.model.step_mixed(
+        logits, cache, rows = self.model.step_mixed(
             params, tokens, cache, lens, new_lens, fused=fused,
-            attn_window=attn_window, all_logits=True,
+            attn_window=attn_window, all_logits=True, count_experts=True,
         )
         # drafts sit in token columns 1..d: column j's logits judge the
         # token in column j+1 (the shifted view; last column is padding)
@@ -451,22 +473,23 @@ class ServingEngine:
             [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1)
         verdict, key = verify_tokens(logits, drafts, key,
                                      self.cfg.temperature)
-        return verdict, cache, key
+        return verdict, cache, key, rows
 
     def _spec_step_paged_fn(self, params, pool, tables, chunks, tok, lens,
                             new_lens, is_decode, key, attn_window: int):
         self.mixed_traces += 1
         fused = self.model.fused_decode_weights(params)
         tokens = self._mixed_tokens(chunks, tok, is_decode)
-        logits, pool = self.model.step_mixed(
+        logits, pool, rows = self.model.step_mixed(
             params, tokens, pool, lens, new_lens, fused=fused,
             page_table=tables, attn_window=attn_window, all_logits=True,
+            count_experts=True,
         )
         drafts = jnp.concatenate(
             [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1)
         verdict, key = verify_tokens(logits, drafts, key,
                                      self.cfg.temperature)
-        return verdict, pool, key
+        return verdict, pool, key, rows
 
     def warm_spec_traces(self, ks: Sequence[int]) -> int:
         """Pre-compile the spec-verify trace grid: for each draft depth's
@@ -702,6 +725,7 @@ class PumpReport:
     drafted_tokens: int = 0           # draft tokens dispatched for verification
     accepted_tokens: int = 0          # drafts that survived verification
     spec_rounds: int = 0              # fused verify dispatches (>=1 draft in)
+    expert_rows: int = 0              # (token, held expert) rows computed
     # per-pump phase walls (the observability breakdown of ``wall_s``),
     # read from the pump's phase spans: admission (``pump.admit``: queue
     # pops + prefill setup/dispatch in legacy mode), dispatch
@@ -1371,18 +1395,19 @@ class QueueSession:
         with self._phase("pump.decode") as sp:
             active = jnp.asarray(slots.request_id >= 0)
             if self.paged:
-                self.cache, self.tok, self.lens, self.key, toks = eng._chunk_paged(
+                self.cache, self.tok, self.lens, self.key, toks, rows = eng._chunk_paged(
                     eng.params, self.cache, jnp.asarray(self.tables),
                     self.tok, self.lens, active, self.key, chunk
                 )
             else:
-                self.cache, self.tok, self.lens, self.key, toks = eng._chunk(
+                self.cache, self.tok, self.lens, self.key, toks, rows = eng._chunk(
                     eng.params, self.cache, self.tok, self.lens, active,
                     self.key, chunk
                 )
         report.dispatch_s = sp.wall_s
         with self._phase("pump.decode_sync") as sp:
-            toks_np = np.asarray(toks)                # ONE transfer per chunk
+            toks_np, rows = jax.device_get((toks, rows))   # ONE read per chunk
+            report.expert_rows += _rows_of(rows)
             n_slots = slots.n_slots
             for t in range(chunk):
                 active = np.nonzero(slots.request_id >= 0)[0]
@@ -1422,6 +1447,7 @@ class QueueSession:
         tel.prefilled_tokens += report.prefilled_tokens
         tel.recovered_tokens += report.recovered_tokens
         tel.recomputed_prefill_tokens += report.recomputed_prefill_tokens
+        tel.expert_rows += report.expert_rows
         return report
 
     def _admit_legacy(self, report: PumpReport) -> None:
@@ -1691,8 +1717,9 @@ class QueueSession:
             # flush the deferred emitted-token reads (one D2H per step, all
             # issued after the dispatches), then the completions they finish
             with self._phase("pump.emit_sync") as sp:
-                for tok_dev, pairs in emits:
-                    vals = np.asarray(tok_dev)
+                for tok_dev, pairs, rows in emits:
+                    vals, rows = jax.device_get((tok_dev, rows))
+                    report.expert_rows += _rows_of(rows)
                     for s, rid in pairs:
                         val = int(vals[s])
                         self._out[rid].append(val)
@@ -1714,12 +1741,14 @@ class QueueSession:
                 active_j = jnp.asarray(decode_active)
                 lens_dev = jnp.asarray(self._lens_host, jnp.int32)
                 if self.paged:
-                    self.cache, self.tok, self.lens, self.key, toks = eng._chunk_paged(
+                    (self.cache, self.tok, self.lens, self.key, toks,
+                     rows) = eng._chunk_paged(
                         eng.params, self.cache, jnp.asarray(self.tables),
                         self.tok, lens_dev, active_j, self.key, chunk
                     )
                 else:
-                    self.cache, self.tok, self.lens, self.key, toks = eng._chunk(
+                    (self.cache, self.tok, self.lens, self.key, toks,
+                     rows) = eng._chunk(
                         eng.params, self.cache, self.tok, lens_dev, active_j,
                         self.key, chunk
                     )
@@ -1728,7 +1757,8 @@ class QueueSession:
                 )
             report.dispatch_s += sp.wall_s
             with self._phase("pump.decode_sync") as sp:
-                toks_np = np.asarray(toks)            # ONE transfer per chunk
+                toks_np, rows = jax.device_get((toks, rows))  # ONE read per chunk
+                report.expert_rows += _rows_of(rows)
                 for t in range(chunk):
                     active = np.nonzero(slots.request_id >= 0)[0]
                     for s in active:
@@ -1764,6 +1794,7 @@ class QueueSession:
         tel.drafted_tokens += report.drafted_tokens
         tel.accepted_tokens += report.accepted_tokens
         tel.spec_rounds += report.spec_rounds
+        tel.expert_rows += report.expert_rows
         return report
 
     def _admit_wave(self, report: PumpReport) -> None:
@@ -1801,8 +1832,8 @@ class QueueSession:
         self._emit_restored(report)
 
     def _ingest(self, report: PumpReport, sched: List[Tuple[int, np.ndarray]]
-                ) -> Tuple[List[Tuple[Any, List[Tuple[int, int]]]], List[int],
-                           float]:
+                ) -> Tuple[List[Tuple[Any, List[Tuple[int, int]], Any]],
+                           List[int], float]:
         """Drive this pump's admissions to completion: every iteration is
         one budget-bounded mixed step, and decode rows advance a token in
         each — ingestion wall is decode wall, never a stall (the legacy
@@ -1810,13 +1841,13 @@ class QueueSession:
         with every decode slot idle while it does).  Emitted-token reads
         are deferred past the loop: the carried-token arrays stay valid
         (tok is never donated), so the steps pipeline with no per-step
-        sync.  Returns the deferred (token array, (slot, rid) pairs) reads,
-        the rids the steps finished, and the seconds spent in the paged
-        tier's ``pump.publish_sync`` logits reads."""
+        sync.  Returns the deferred (token array, (slot, rid) pairs, expert
+        rows) reads, the rids the steps finished, and the seconds spent in
+        the paged tier's ``pump.publish_sync`` logits reads."""
         eng, slots = self.eng, self.slots
         n_slots = slots.n_slots
         greedy = eng.cfg.temperature <= 0.0
-        deferred_emits: List[Tuple[Any, List[Tuple[int, int]]]] = []
+        deferred_emits: List[Tuple[Any, List[Tuple[int, int]], Any]] = []
         deferred_done: List[int] = []
         publish_s = 0.0
         while sched:
@@ -1832,7 +1863,7 @@ class QueueSession:
             # pairs emit now, read the values after the loop
             pairs = [(int(s), int(slots.request_id[s]))
                      for s in np.nonzero(decode_active)[0]]
-            deferred_emits.append((self.tok, pairs))
+            carried = self.tok
             is_decode = jnp.asarray(decode_active)
             # attention window: pow-2 bucket over the step's content
             # frontier, so score work tracks real lengths, not max_len.
@@ -1849,16 +1880,17 @@ class QueueSession:
             # the device, so the mirror is the single source of truth here
             lens_dev = jnp.asarray(self._lens_host, jnp.int32)
             if self.paged:
-                logits, self.cache, self.lens = eng._mixed_paged(
+                logits, self.cache, self.lens, rows = eng._mixed_paged(
                     eng.params, self.cache, jnp.asarray(self.tables),
                     jnp.asarray(chunks_np), self.tok, lens_dev,
                     jnp.asarray(new_lens), is_decode, aw,
                 )
             else:
-                logits, self.cache, self.lens = eng._mixed(
+                logits, self.cache, self.lens, rows = eng._mixed(
                     eng.params, self.cache, jnp.asarray(chunks_np), self.tok,
                     lens_dev, jnp.asarray(new_lens), is_decode, aw,
                 )
+            deferred_emits.append((carried, pairs, rows))
             self._lens_host += new_lens
             report.mixed_steps += 1
             # rows finishing their prompt THIS step start decoding from the
@@ -1987,18 +2019,19 @@ class QueueSession:
             draft.end()
             with self._phase("pump.decode") as disp:
                 if self.paged:
-                    verdict, self.cache, self.key = eng._spec_paged(
+                    verdict, self.cache, self.key, rows = eng._spec_paged(
                         eng.params, self.cache, jnp.asarray(self.tables),
                         jnp.asarray(chunks_np), tok_dev, lens_dev,
                         jnp.asarray(new_lens), is_decode, self.key, aw,
                     )
                 else:
-                    verdict, self.cache, self.key = eng._spec(
+                    verdict, self.cache, self.key, rows = eng._spec(
                         eng.params, self.cache, jnp.asarray(chunks_np), tok_dev,
                         lens_dev, jnp.asarray(new_lens), is_decode, self.key, aw,
                     )
             sync = self._phase("pump.decode_sync")
-            v = np.asarray(verdict)           # ONE (3, B, Q) transfer/round
+            v, rows = jax.device_get((verdict, rows))  # ONE read a round
+            report.expert_rows += _rows_of(rows)
             counts = np.zeros(n_slots, np.int64)
             round_drafted = 0
             round_accepted = 0

@@ -86,9 +86,11 @@ def test_smoke_prefill(arch):
 
 
 def test_shape_grid_cells():
-    """DESIGN.md §4: the runnable grid is 32 cells."""
+    """DESIGN.md §4's grid of 32 cells, and mellum2-12b-a2.5b's three
+    (train, prefill, decode; full layers keep it off long_500k)."""
     total = sum(len(shape_grid(get_config(a))) for a in ARCH_IDS)
-    assert total == 32
+    assert total == 35
+    assert len(shape_grid(get_config("mellum2-12b-a2.5b"))) == 3
     assert len(shape_grid(get_config("hubert-xlarge"))) == 2
     assert len(shape_grid(get_config("rwkv6-7b"))) == 4
     assert len(shape_grid(get_config("llama3-405b"))) == 3
@@ -107,6 +109,7 @@ def test_param_counts_sane():
         "zamba2-2.7b": (2e9, 4e9),
         "llava-next-mistral-7b": (6e9, 8e9),
         "hubert-xlarge": (0.7e9, 1.3e9),
+        "mellum2-12b-a2.5b": (11e9, 13e9),
     }
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()
